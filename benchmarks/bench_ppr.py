@@ -40,12 +40,6 @@ from repro.serving.ppr_engine import PPREngine, make_query_stream
 from repro.serving.runtime import ServingRuntime
 
 
-def _engine_opts(backend: str) -> dict:
-    from repro.utils.jaxcompat import on_tpu
-
-    return {} if backend == "jax" else {"interpret": not on_tpu()}
-
-
 def bench(scale: int = 9, avg_degree: int = 8, queries: int = 64,
           slots: int = 8, threshold: float = 1e-6, backend: str = "jax",
           iters_per_step: int = 8, top_k: int = 10, seed: int = 0) -> dict:
@@ -55,7 +49,7 @@ def bench(scale: int = 9, avg_degree: int = 8, queries: int = 64,
                          "(percentiles of an empty stream are undefined)")
     g = rmat_graph(scale, avg_degree=avg_degree, seed=seed)
     eng = PPREngine(g, slots=slots, threshold=threshold, backend=backend,
-                    iters_per_step=iters_per_step, **_engine_opts(backend))
+                    iters_per_step=iters_per_step)
     qs = make_query_stream(g.n, queries, top_k=top_k, seed=seed)
     # warmup traces/compiles the jitted batched step; the measured run then
     # REUSES this engine (a fresh engine would re-jit inside the timed
@@ -108,8 +102,7 @@ def bench_load(scale: int = 9, avg_degree: int = 8, queries: int = 64,
 
     def _make_runtime() -> ServingRuntime:
         eng = PPREngine(g, slots=slots, threshold=threshold, backend=backend,
-                        iters_per_step=iters_per_step,
-                        **_engine_opts(backend))
+                        iters_per_step=iters_per_step)
         rt = ServingRuntime(eng, queue_depth=queue_depth)
         rt.serve(warm_qs)  # warm the trace outside the measured runs
         return rt

@@ -51,7 +51,7 @@ from repro.core import PartitionedGraph, l1_norm, pagerank_numpy
 from repro.core.solver import get_variant, list_variants, plan_stats
 from repro.core.runtime import simulate_jittered
 from repro.graphs import make_dataset
-from repro.utils.jaxcompat import on_tpu
+from repro.utils.platform import pallas_interpret
 
 THRESH = 1e-8
 P = 56  # the paper's thread count
@@ -62,8 +62,6 @@ LOCAL_SWEEPS = 2
 # mean-sweep units (simulate_jittered docstring) — the regime where the
 # adaptive schedule's shed sweeps also shed their stall exposure
 STALL_PROB, STALL_DUR = 0.1, 5.0
-
-INTERPRET = not on_tpu()
 
 ENVELOPE_PATH = (pathlib.Path(__file__).resolve().parents[1]
                  / "tests" / "data" / "trajectory_envelopes.json")
@@ -95,8 +93,7 @@ def bench_records(name: str, scale_down: float = SCALE_DOWN,
         if kind not in bundles:
             bundles[kind] = v.build(g, threads=P)
         bundle = bundles[kind]
-        fn = lambda: v.run(bundle, threshold=THRESH, interpret=INTERPRET,
-                           local_sweeps=LOCAL_SWEEPS)
+        fn = lambda: v.run(bundle, threshold=THRESH, local_sweeps=LOCAL_SWEEPS)
         r = fn()
         wall = time_call(fn)
         iters = int(r.iterations)
@@ -182,7 +179,7 @@ def bench_records(name: str, scale_down: float = SCALE_DOWN,
             # the sweeps it skipped
             "sim_stalled_speedup_vs_seq": sim_seq_stalled / sim_stalled,
             "l1_vs_oracle": l1_norm(r.pr, ref),
-            "interpreted": bool(v.backend == "pallas" and INTERPRET),
+            "interpreted": bool(v.backend == "pallas" and pallas_interpret()),
             "core_n": ps["core_n"] if ps else g.n,
             "core_m": ps["core_m"] if ps else g.m,
             "pruned_edges": ps["pruned_edges"] if ps else 0,

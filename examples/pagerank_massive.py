@@ -1,30 +1,28 @@
 """Distributed stale-synchronous PageRank (the paper's No-Sync on a mesh).
 
-Runs the shard_map solver over 8 simulated devices and compares the
-barrier schedule (one exchange per sweep) with bounded-staleness schedules
-(k local Gauss-Seidel sweeps per exchange) — same fixed point, k× fewer
-collectives. On a real pod, replace the host-device flag with the slice.
+Runs the shard_map solver with one shard per device present and compares
+the barrier schedule (one exchange per sweep) with bounded-staleness
+schedules (k local Gauss-Seidel sweeps per exchange) — same fixed point, k×
+fewer collectives.  On one CPU, simulate a mesh with host devices:
 
-    PYTHONPATH=src python examples/pagerank_massive.py
+    PYTHONPATH=src XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+        python examples/pagerank_massive.py
 """
-import os
-
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-
 import time
 
 import jax
 
 from repro.core import PartitionedGraph, distributed_pagerank, l1_norm, pagerank_numpy
+from repro.core.distributed import solver_mesh
 from repro.graphs import make_dataset
 
 g = make_dataset("socLiveJournal1", scale_down=2048)  # surrogate, ~2.4k vertices
-print(f"graph: n={g.n} m={g.m}; devices={len(jax.devices())}")
+p = jax.device_count()
+print(f"graph: n={g.n} m={g.m}; devices={p}")
 ref, _ = pagerank_numpy(g, threshold=1e-12)
 
-pg = PartitionedGraph.from_graph(g, p=8)
-from repro.utils.jaxcompat import make_mesh
-mesh = make_mesh((8,), ("data",))
+pg = PartitionedGraph.from_graph(g, p=p)
+mesh = solver_mesh(p)
 
 for mode, k in (("barrier", 1), ("stale", 2), ("stale", 4)):
     t0 = time.perf_counter()

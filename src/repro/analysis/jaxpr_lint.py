@@ -151,7 +151,7 @@ def trace_variant(name: str):
     if v.backend == "numpy":
         return None
     opts = dict(threads=2, block=8, tile_cap=16, local_sweeps=2,
-                send_fraction=0.5, interpret=True)
+                send_fraction=0.5)
     v, bundle = build_variant(name, _tiny_graph(), **opts)
     run, target_bundle = v.run, bundle
     if isinstance(bundle, PlannedBundle):
@@ -191,7 +191,7 @@ def jaxpr_findings(names: Iterable[str] | None = None) -> list[Finding]:
 # end-to-end, no host round-trips inside the jitted step.
 SERVING_BACKENDS = (
     ("jax", {}),
-    ("pallas", dict(block=8, tile_cap=16, interpret=True)),
+    ("pallas", dict(block=8, tile_cap=16)),
 )
 
 

@@ -35,13 +35,15 @@ import functools
 from typing import Any, Callable, Sequence
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.analysis.findings import Finding
 
-# ~16 MB of VMEM per TensorCore (v4/v5 generations; docs/KERNELS.md quotes
-# the same figure).  The analyzer treats this as the hard budget.
+# v5e's default scoped VMEM per kernel (docs/KERNELS.md quotes the same
+# figure); the kernels may raise it, so the analyzer's budget is the size a
+# kernel fits without doing so.
 VMEM_BYTES = 16 * 2**20
 
 # Sentinel primes: each symbol gets a distinct value no other dimension can
@@ -55,10 +57,13 @@ _VALUE_TO_SYMBOL = {v: k for k, v in SYMBOLS.items()}
 def _symbolize(shape: Sequence[int]) -> tuple:
     """Map a sentinel-valued shape to its symbolic form, e.g. (5, 7) ->
     ("n_blocks", "block"); dims that match no sentinel stay literal ints."""
-    return tuple(_VALUE_TO_SYMBOL.get(int(d), int(d)) for d in shape)
+    return tuple(None if d is None else _VALUE_TO_SYMBOL.get(int(d), int(d))
+                 for d in shape)
 
 
 def _eval_dim(dim, env: dict) -> int:
+    if dim is None:  # squeezed block dim: one element
+        return 1
     return int(env[dim]) if isinstance(dim, str) else int(dim)
 
 
@@ -74,7 +79,7 @@ class Operand:
     """One pallas_call operand's symbolic memory contract."""
 
     name: str
-    kind: str  # "prefetch" | "input" | "output" | "scratch"
+    kind: str  # "prefetch" | "smem" | "input" | "output" | "scratch"
     shape: tuple  # symbolic full shape
     block_shape: tuple | None  # symbolic BlockSpec shape (None: no BlockSpec)
     dtype: str
@@ -143,8 +148,8 @@ class KernelReport:
         env.update(block=block, cap=cap, b=b)
         total = 0
         for o in self.operands:
-            if o.kind == "prefetch":
-                continue  # scalar prefetch lives in SMEM, not VMEM
+            if o.kind in ("prefetch", "smem"):
+                continue  # scalar prefetch and SMEM operands are not VMEM
             if o.kind == "scratch":
                 total += o.block_bytes(env)
             elif o.resident and not o.scales_with_vertices():
@@ -218,6 +223,7 @@ class _Captured:
     def __init__(self):
         self.grid_spec = None
         self.out_shape = None
+        self.operand_shapes = None
 
 
 def capture_grid_spec(fn: Callable, args: Sequence[Any], **static) -> Any:
@@ -225,28 +231,34 @@ def capture_grid_spec(fn: Callable, args: Sequence[Any], **static) -> Any:
     record its grid spec instead of compiling/executing anything.
 
     ``fn`` may be a plain function or a ``jax.jit`` wrapper (its
-    ``__wrapped__`` is used); ``args`` are typically ``ShapeDtypeStruct``\\ s
-    — the kernel wrappers only read ``.shape``/``.dtype`` outside the
-    ``pallas_call``.  Returns ``(grid_spec, out_shape)`` — the grid spec
-    object exposes ``grid``, ``in_specs``, ``out_specs``, ``scratch_shapes``,
-    ``num_scalar_prefetch``."""
+    ``__wrapped__`` is used); ``args`` are typically ``ShapeDtypeStruct``\\ s,
+    traced abstractly (``jax.eval_shape``) so the wrappers' own reshapes
+    around the ``pallas_call`` work without data.  Returns ``(grid_spec,
+    out_shape, operand_shapes)``: the grid spec exposes ``grid``,
+    ``in_specs``, ``out_specs``, ``scratch_shapes``, ``num_scalar_prefetch``;
+    ``operand_shapes`` are the ``(shape, dtype)`` of the pallas_call's
+    operands, in its argument order."""
     cap = _Captured()
 
     def fake_pallas_call(kernel, *, grid_spec=None, out_shape=None, **_kw):
         cap.grid_spec = grid_spec
         cap.out_shape = out_shape
-        return lambda *call_args: out_shape
+        def call(*call_args):
+            cap.operand_shapes = [(tuple(a.shape), a.dtype) for a in call_args]
+            return jnp.zeros(out_shape.shape, out_shape.dtype)
+
+        return call
 
     target = getattr(fn, "__wrapped__", fn)
     orig = pl.pallas_call
     pl.pallas_call = fake_pallas_call
     try:
-        target(*args, **static)
+        jax.eval_shape(functools.partial(target, **static), *args)
     finally:
         pl.pallas_call = orig
     if cap.grid_spec is None:
         raise RuntimeError(f"{fn} never invoked pl.pallas_call")
-    return cap.grid_spec, cap.out_shape
+    return cap.grid_spec, cap.out_shape, cap.operand_shapes
 
 
 def _index_map_samples(grid_spec, t_values, n_blocks: int):
@@ -311,7 +323,7 @@ def analyze_grid_spec(grid_spec, arg_shapes: Sequence, operand_names:
         bs = tuple(spec.block_shape)
         outputs = set()
         ok = True
-        nblocks_per_dim = [max(1, -(-int(full_shape[d]) // int(bs[d])))
+        nblocks_per_dim = [max(1, -(-int(full_shape[d]) // _eval_dim(bs[d], {})))
                            for d in range(len(bs))]
         for sb, db in samples:
             for t in t_values:
@@ -335,6 +347,11 @@ def analyze_grid_spec(grid_spec, arg_shapes: Sequence, operand_names:
 
     for i, spec in enumerate(in_specs):
         shp, dt = arg_shapes[nsp + i]
+        if spec.block_shape is None:  # whole array in SMEM (scalar params)
+            operands.append(Operand(_name(nsp + i), "smem", _symbolize(shp),
+                                    None, str(np.dtype(dt)),
+                                    np.dtype(dt).itemsize, resident=True))
+            continue
         operands.append(_classify(spec, shp, dt, _name(nsp + i), "input"))
 
     for j, (spec, osh) in enumerate(zip(out_list, out_shapes)):
@@ -360,71 +377,42 @@ def analyze_grid_spec(grid_spec, arg_shapes: Sequence, operand_names:
 
 
 def _S(*dims, dtype=np.float32):
-    env = SYMBOLS
-    shape = tuple(_eval_dim(d, env) for d in dims)
-    return jax.ShapeDtypeStruct(shape, dtype), (shape, dtype)
+    return jax.ShapeDtypeStruct(tuple(_eval_dim(d, SYMBOLS) for d in dims), dtype)
 
 
-def _family_specs() -> dict[str, dict]:
-    """Symbolic call descriptions of the three kernels, in signature order.
-
-    The operand name lists follow **pallas_call argument order** (prefetch
-    first, output last) — a signature change shows up as an
-    ``operand-count-drift`` finding rather than silently skewing the table.
-    """
+def _family_specs() -> dict[str, tuple]:
+    """Symbolic calls of the three kernels: the wrapper, its arguments in
+    signature order, and the operand names in **pallas_call order**
+    (prefetch first, output last).  The operand shapes themselves are
+    captured from the call, so a layout change needs no edit here, and a
+    signature change shows up as an ``operand-count-drift`` finding rather
+    than silently skewing the table."""
     from repro.kernels.spmv import kernel as K
 
-    def blocked():
-        args, shapes = zip(
-            _S("n_blocks", "block"),
-            _S("T", "cap", dtype=np.int32), _S("T", "cap", dtype=np.int32),
-            _S("T", "cap"),
-            _S("T", dtype=np.int32), _S("T", dtype=np.int32),
-        )
-        # pallas_call order: (tile_src_block, tile_dst_block, contrib,
-        #                     tiles_src, tiles_dst, tiles_valid) -> acc
-        order = [4, 5, 0, 1, 2, 3]
-        return (K.spmv_blocked, args, [shapes[i] for i in order],
-                ["tile_src_block", "tile_dst_block", "contrib_blocks",
-                 "tiles_src_local", "tiles_dst_local", "tiles_valid",
-                 "acc_blocks"])
-
-    def gs_pass():
-        args, shapes = zip(
-            _S("n_blocks", "block"), _S("n_blocks", "block"),
-            _S("n_blocks", "block"), _S("n_blocks", "block"),
-            _S("n_blocks", "block"),
-            _S(1, 3),
-            _S("T", "cap", dtype=np.int32), _S("T", "cap", dtype=np.int32),
-            _S("T", "cap"), _S("T", "cap"),
-            _S("T", dtype=np.int32), _S("T", dtype=np.int32),
-        )
-        order = [10, 11, 5, 0, 1, 2, 3, 4, 6, 7, 8, 9]
-        return (K.spmv_gs_pass, args, [shapes[i] for i in order],
-                ["tile_src_block", "tile_dst_block", "params", "pr_blocks",
-                 "inv_out_blocks", "vmask_blocks", "bias_blocks",
-                 "frozen_blocks", "tiles_src_local", "tiles_dst_local",
-                 "tiles_valid", "tiles_weight", "pr_state"])
-
-    def gs_multi():
-        args, shapes = zip(
-            _S("n_blocks", "b", "block"), _S("n_blocks", "block"),
-            _S("n_blocks", "block"), _S(1, "b"),
-            _S("n_blocks", "b", "block"),
-            _S(1, 1),
-            _S("T", "cap", dtype=np.int32), _S("T", "cap", dtype=np.int32),
-            _S("T", "cap"), _S("T", "cap"),
-            _S("T", dtype=np.int32), _S("T", dtype=np.int32),
-        )
-        order = [10, 11, 5, 0, 1, 2, 3, 4, 6, 7, 8, 9]
-        return (K.spmv_gs_pass_multi, args, [shapes[i] for i in order],
-                ["tile_src_block", "tile_dst_block", "params", "pr_blocks",
-                 "inv_out_blocks", "vmask_blocks", "frozen_rows",
-                 "base_blocks", "tiles_src_local", "tiles_dst_local",
-                 "tiles_valid", "tiles_weight", "pr_state"])
-
-    return {"spmv_blocked": blocked, "spmv_gs_pass": gs_pass,
-            "spmv_gs_pass_multi": gs_multi}
+    tiles = (_S("T", "cap", dtype=np.int32), _S("T", "cap", dtype=np.int32),
+             _S("T", "cap"), _S("T", "cap"))
+    maps = (_S("T", dtype=np.int32), _S("T", dtype=np.int32))
+    vertex = _S("n_blocks", "block")
+    panel = _S("n_blocks", "b", "block")
+    tile_names = ["tiles_src_local", "tiles_dst_local", "tiles_valid",
+                  "tiles_weight"]
+    return {
+        "spmv_blocked": (
+            K.spmv_blocked, (vertex,) + tiles[:3] + maps,
+            ["tile_src_block", "tile_dst_block", "contrib_blocks"]
+            + tile_names[:3] + ["acc_blocks"]),
+        "spmv_gs_pass": (
+            K.spmv_gs_pass, (vertex,) * 5 + (_S(1, 3),) + tiles + maps,
+            ["tile_src_block", "tile_dst_block", "params", "pr_blocks",
+             "inv_out_blocks", "vmask_blocks", "bias_blocks", "frozen_blocks"]
+            + tile_names + ["pr_state"]),
+        "spmv_gs_pass_multi": (
+            K.spmv_gs_pass_multi,
+            (panel, vertex, vertex, _S(1, "b"), panel, _S(1, 1)) + tiles + maps,
+            ["tile_src_block", "tile_dst_block", "params", "pr_blocks",
+             "inv_out_blocks", "vmask_blocks", "frozen_rows", "base_blocks"]
+            + tile_names + ["pr_state"]),
+    }
 
 
 @functools.lru_cache(maxsize=1)
@@ -432,12 +420,11 @@ def analyze_kernels() -> dict[str, KernelReport]:
     """Capture + analyze the whole SpMV kernel family (cached — the capture
     costs one Python call per kernel, no compilation)."""
     reports = {}
-    for name, make in _family_specs().items():
-        fn, args, arg_shapes, names = make()
-        gs, out_shape = capture_grid_spec(fn, args, block=SYMBOLS["block"],
-                                          interpret=True)
-        reports[name] = analyze_grid_spec(gs, arg_shapes, names, kernel=name,
-                                          out_shape=out_shape)
+    for name, (fn, args, names) in _family_specs().items():
+        gs, out_shape, operand_shapes = capture_grid_spec(
+            fn, args, block=SYMBOLS["block"])
+        reports[name] = analyze_grid_spec(gs, operand_shapes, names,
+                                          kernel=name, out_shape=out_shape)
     return reports
 
 
@@ -519,9 +506,12 @@ def kernels_markdown(*, block: int = 256, cap: int = 1024) -> str:
     multi = reps["spmv_gs_pass_multi"]
     lines += [
         "",
-        f"Budget: {VMEM_BYTES // 2**20} MiB/core; streamed tiles are "
-        f"double-buffered (2 blocks in flight), scalar-prefetch maps live in "
-        f"SMEM.  At `block={block}`, `cap={cap}` the global GS pass keeps "
+        f"Budget: {VMEM_BYTES // 2**20} MiB/core, v5e's default scoped VMEM "
+        f"(the kernels raise their scoped limit from the operand sizes, up to "
+        f"the core's 128 MiB); resident operands are single-buffered, streamed "
+        f"rows double-buffered (2 blocks in flight); scalar-prefetch maps and "
+        f"scalar params live in SMEM.  At `block={block}`, `cap={cap}` the "
+        f"global GS pass keeps "
         f"{gs.per_vertex_expr()} B/vertex resident → "
         f"**~{gs.max_vertices_per_core(block=block, cap=cap):,} vertices/"
         f"core**; the multi-vector pass keeps {multi.per_vertex_expr()} "

@@ -38,11 +38,10 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.core.pagerank import DEFAULT_DAMPING, PageRankResult, PartitionedGraph
 from repro.core.solver import register_variant
-from repro.utils.jaxcompat import make_mesh, shard_map
 
 
 def _sweep(pr_full, local, srcs, dsts, emask, inv_out, base, d, vp, offset):
@@ -148,7 +147,7 @@ def distributed_pagerank(
     # partitioned alongside the edges); the bias vector is one extra
     # replicated operand, present only on biased graphs
     extra = () if pg.bias_pad is None else (pg.bias_pad,)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         solver,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(), P())
@@ -251,7 +250,7 @@ def distributed_pagerank_topk(
         return local, err_global[None], rounds[None]
 
     extra = () if pg.bias_pad is None else (pg.bias_pad,)
-    mapped = shard_map(
+    mapped = jax.shard_map(
         solver,
         mesh=mesh,
         in_specs=(P(axis, None), P(axis, None), P(axis, None), P(), P())
@@ -290,7 +289,7 @@ def solver_mesh(p: Optional[int] = None, axis: str = "data") -> Mesh:
     partitions on a single-host run degrades gracefully instead of raising."""
     n_dev = jax.device_count()
     p = n_dev if p is None else max(1, min(int(p), n_dev))
-    return make_mesh((p,), (axis,))
+    return jax.make_mesh((p,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def _dist_build(g, threads: int = 8, **_) -> DistributedBundle:

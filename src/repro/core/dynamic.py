@@ -342,11 +342,14 @@ def random_update_batch(
             adds = np.stack([us, vs], axis=1)
         return adds, dels
 
-    n_dels = min(n_dels, src.size)
+    # one slot per distinct edge: deleting a parallel edge's key twice in
+    # one batch is refused by apply_updates
+    _, distinct = np.unique(key, return_index=True)
+    n_dels = min(n_dels, distinct.size)
     dels = None
     surviving = key
     if n_dels:
-        pick = rng.choice(src.size, size=n_dels, replace=False)
+        pick = distinct[rng.choice(distinct.size, size=n_dels, replace=False)]
         dels = np.stack([src[pick], dst[pick]], axis=1)
         surviving = np.delete(key, pick)
     adds_list: list[np.ndarray] = []

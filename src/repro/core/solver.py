@@ -512,7 +512,7 @@ class Variant:
       never shared).
     * ``backend`` — what executes the sweeps: ``"numpy"`` (host oracle),
       ``"jax"`` (jitted single-device), ``"pallas"`` (Pallas kernels — run
-      interpreted off-TPU, and benchmarks flag that), ``"shard_map"``
+      interpreted on the CPU backend, and benchmarks flag that), ``"shard_map"``
       (device-mesh collectives).
     * ``schedule`` — coordination discipline for the runtime cost model:
       ``"barrier"``, ``"nosync"`` (fresh/stale reads, no global barrier),

@@ -8,15 +8,25 @@ touches a single ``block``-sized slice of the contribution vector and a single
 On a CPU the binning/accumulate phases fight DRAM; on TPU the analogous
 enemy is HBM→VMEM traffic *and* the lack of fast random gather/scatter.
 We remove gather/scatter entirely: within a tile, gather and scatter are both
-expressed as **one-hot matmuls on the MXU**::
+expressed as **one-hot matmuls on the MXU**, with the one-hot laid out
+``(block, cap)`` so the tile's index row broadcasts along sublanes::
 
-    gathered(cap)  = onehot(src_local)(cap×block) @ contrib(block)
-    acc(block)    += valid·gathered(cap) @ onehot(dst_local)(cap×block)
+    gathered(r, cap) = contrib(r, block) @ onehot(src_local)(block, cap)
+    acc(r, block)   += (w·gathered)(r, cap) @ onehot(dst_local)(block, cap)ᵀ
 
-The FLOP inflation is irrelevant — the kernel stays memory-bound (per tile:
-~3·cap·4B of edge indices from HBM vs 4·cap·block FLOPs on a 197-TFLOP/s MXU;
-with cap=1024, block=256 the MXU time is ~5 ns vs ~15 ns of HBM time), so
-the kernel runs at the HBM roofline of the SpMV.
+(``r`` = 1 for the global kernels, the batch for the PPR kernel).
+
+Per tile the kernel reads ~3·cap·4 B of edge indices from HBM against
+4·cap·block MXU FLOPs per batch row.  On a v5e at block 1024 / cap 128 a
+grid step costs ~0.9 µs, far above either the HBM or the MXU time of its
+tile, so the kernel is bound by its per-step cost, not by HBM (PERF.md).
+
+Layout rules the TPU compiler imposes (and interpret mode does not): every
+block's last two dims must be (8, 128)-aligned or span the whole array, so
+per-tile and per-block rows are streamed from ``(T, 1, cap)`` /
+``(n_blocks, 1, block)`` views with the leading dim squeezed (``None``), and
+whole-pass state is one full ``(n_blocks, block)`` block addressed by row
+(``ref[pl.ds(i, 1), :]``).  Scalars ride in SMEM.
 
 Grid: one step per tile, tiles sorted by dst_block → each output block is
 resident in VMEM for one contiguous run of grid steps (standard Pallas
@@ -32,21 +42,74 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.utils.platform import pallas_interpret
 
-def _tile_gather_scatter(src, dst, val, contrib):
-    """One tile's gather→mask→scatter as two one-hot MXU matmuls; both
-    schedules' kernels share this so their tile math stays identical.
+# one-hot entries are exact in bf16 but the ranks are not: contract in f32
+_PRECISION = jax.lax.Precision.HIGHEST
+# v5e's default scoped VMEM limit, and the chip's physical VMEM per core
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+_VMEM_CAPACITY = 128 * 2**20
 
-    src/dst: (cap,) int32 local ids; val: (cap,) f32 validity;
-    contrib: (block,) — returns the (block,) partial accumulator."""
+
+def _onehot(local_ids, block: int):
+    """``(1, cap)`` int32 row of block-local ids → ``(block, cap)`` f32
+    one-hot; the row broadcasts along sublanes, so no transpose is needed."""
+    ids = jax.lax.broadcasted_iota(jnp.int32, (block, local_ids.shape[-1]), 0)
+    return (ids == local_ids).astype(jnp.float32)
+
+
+def _tile_gather_scatter(src, dst, w, contrib):
+    """One tile's gather→scale→scatter as two one-hot MXU matmuls; every
+    kernel shares this so their tile math stays identical.
+
+    src/dst: (1, cap) int32 local ids; w: (1, cap) f32 validity·weight;
+    contrib: (r, block) — returns the (r, block) partial accumulator."""
     block = contrib.shape[-1]
-    ids = jax.lax.broadcasted_iota(jnp.int32, (src.shape[0], block), 1)
-    onehot_src = (src[:, None] == ids).astype(jnp.float32)  # (cap, block)
-    gathered = jnp.dot(onehot_src, contrib.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)  # (cap,)
-    vals = gathered * val
-    onehot_dst = (dst[:, None] == ids).astype(jnp.float32)  # (cap, block)
-    return jnp.dot(vals, onehot_dst, preferred_element_type=jnp.float32)  # (block,)
+    gathered = jnp.dot(contrib.astype(jnp.float32), _onehot(src, block),
+                       precision=_PRECISION,
+                       preferred_element_type=jnp.float32)  # (r, cap)
+    return jax.lax.dot_general(
+        gathered * w, _onehot(dst, block), (((1,), (1,)), ((), ())),
+        precision=_PRECISION, preferred_element_type=jnp.float32)  # (r, block)
+
+
+def _rows(x):
+    """``(T, cap)`` → ``(T, 1, cap)``: one aligned-or-full block per row."""
+    return x.reshape(x.shape[0], 1, x.shape[1])
+
+
+def _tile_spec(cap: int):
+    return pl.BlockSpec((None, 1, cap), lambda t, sb, db: (t, 0, 0))
+
+
+def _resident_spec(shape):
+    """Whole-array block under a constant index map, single-buffered: it is
+    fetched (or written back) once, so a second buffer would only cost VMEM."""
+    zeros = (0,) * len(shape)
+    return pl.BlockSpec(shape, lambda t, sb, db: zeros,
+                        pipeline_mode=pl.Buffered(1))
+
+
+def _compiler_params(resident_bytes: int, step_bytes: int):
+    """Sequential grid (the Gauss–Seidel order and the output runs depend on
+    it) and a scoped-VMEM limit sized from the operands: the single-buffered
+    resident state, two buffers of every per-step block, and room for the
+    tile body's ``(block, cap)`` one-hot temporaries."""
+    need = resident_bytes + 2 * step_bytes + (4 << 20)
+    if need > _VMEM_CAPACITY:
+        raise ValueError(
+            f"the kernel needs ~{need / 2**20:.0f} MiB of VMEM, more than a "
+            f"TPU core has ({_VMEM_CAPACITY >> 20} MiB); shard the vertex "
+            f"space first (repro.core.distributed)")
+    return pltpu.CompilerParams(
+        dimension_semantics=("arbitrary",),
+        vmem_limit_bytes=max(_DEFAULT_SCOPED_VMEM, need))
+
+
+def _tile_body_bytes(block: int, cap: int, r: int) -> int:
+    """Per-step VMEM of the tile body: the streamed ``(1, cap)`` rows (each
+    padded to 8 sublanes) plus the two one-hot matrices and the panels."""
+    return 4 * (4 * 8 * cap + 2 * block * cap + max(r, 8) * (cap + block))
 
 
 def _spmv_kernel(sb_ref, db_ref, contrib_ref, src_ref, dst_ref, val_ref, out_ref):
@@ -58,9 +121,9 @@ def _spmv_kernel(sb_ref, db_ref, contrib_ref, src_ref, dst_ref, val_ref, out_ref
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    acc = _tile_gather_scatter(src_ref[0, :], dst_ref[0, :], val_ref[0, :],
-                               contrib_ref[0, :])
-    out_ref[0, :] += acc.astype(out_ref.dtype)
+    acc = _tile_gather_scatter(src_ref[...], dst_ref[...], val_ref[...],
+                               contrib_ref[...])
+    out_ref[...] += acc.astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -73,30 +136,29 @@ def spmv_blocked(
     tile_dst_block: jax.Array,  # (T,) int32
     *,
     block: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns acc_blocks (n_blocks, block): sum of contributions per dst."""
     n_blocks = contrib_blocks.shape[0]
     T, cap = tiles_src_local.shape
+    row = pl.BlockSpec((None, 1, block), lambda t, sb, db: (sb[t], 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda t, sb, db: (sb[t], 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda t, sb, db: (db[t], 0)),
+        in_specs=[row, _tile_spec(cap), _tile_spec(cap), _tile_spec(cap)],
+        out_specs=pl.BlockSpec((None, 1, block), lambda t, sb, db: (db[t], 0, 0)),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _spmv_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_blocks, block), contrib_blocks.dtype),
-        interpret=interpret,
-    )(tile_src_block, tile_dst_block, contrib_blocks,
-      tiles_src_local, tiles_dst_local, tiles_valid)
+        out_shape=jax.ShapeDtypeStruct((n_blocks, 1, block), contrib_blocks.dtype),
+        compiler_params=_compiler_params(
+            0, 2 * 4 * 8 * block + _tile_body_bytes(block, cap, 1)),
+        interpret=pallas_interpret(interpret),
+    )(tile_src_block, tile_dst_block, contrib_blocks.reshape(n_blocks, 1, block),
+      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid))
+    return out.reshape(n_blocks, block)
 
 
 # ---------------------------------------------------------------------------
@@ -116,13 +178,13 @@ def spmv_blocked(
 # map → one VMEM-resident buffer across the whole grid, written back once at
 # the end).  Step 0 copies the input ranks in; each dst-block run accumulates
 # its tiles' one-hot-matmul partial sums into a VMEM scratch, then commits
-# ``new_j = (base·bias_j + dmass + d·acc)·vmask_j`` into the state, so later
-# runs gather from it.  The three scalars [base, d, dmass] arrive via a tiny
-# params block (dangling mass kept separate from the base: redistribution is
-# uniform, never bias-scaled); per-edge weights stream per tile and the bias
-# is one more block-layout VMEM operand — see docs/KERNELS.md for the operand
-# table and the resulting ~24 B/vertex VMEM budget (whole-state residency is
-# the right trade below ~600-700k vertices per core; beyond that the nosync
+# ``new_j = (base·bias_j + dmass + d·acc_j)·vmask_j`` into the state, so later
+# runs gather from it.  The three scalars [base, d, dmass] arrive in SMEM
+# (dangling mass kept separate from the base: redistribution is uniform,
+# never bias-scaled); per-edge weights stream per tile and the bias is one
+# more block-layout VMEM operand — see docs/KERNELS.md for the operand table
+# and the resulting ~24 B/vertex VMEM budget (whole-state residency is the
+# right trade while the state fits a core's VMEM; beyond that the nosync
 # schedule shards first, see core/distributed.py).
 
 
@@ -150,31 +212,29 @@ def _spmv_gs_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref, vmask_ref,
     # The per-edge weights operand scales each lane of the one-hot contraction
     # (val·wt folds validity and weight; the unweighted caller passes the
     # {0,1} validity mask for wt, making the product a no-op).
-    contrib = (pl.load(pr_ref, (pl.ds(sb, 1), slice(None))) *
-               pl.load(inv_ref, (pl.ds(sb, 1), slice(None))))[0, :]
-    acc_ref[0, :] += _tile_gather_scatter(src_ref[0, :], dst_ref[0, :],
-                                          val_ref[0, :] * wt_ref[0, :], contrib)
+    src_rows = pl.ds(sb, 1)
+    contrib = pr_ref[src_rows, :] * inv_ref[src_rows, :]
+    acc_ref[...] += _tile_gather_scatter(src_ref[...], dst_ref[...],
+                                         val_ref[...] * wt_ref[...], contrib)
 
     @pl.when(is_run_end)
     def _commit_block():
-        base = params_ref[0, 0]
-        d = params_ref[0, 1]
-        dmass = params_ref[0, 2]
-        vm = pl.load(vmask_ref, (pl.ds(db, 1), slice(None)))[0, :]
+        base = params_ref[0]
+        d = params_ref[1]
+        dmass = params_ref[2]
+        rows = pl.ds(db, 1)
         # per-vertex teleport bias: multiplies the base term only (dangling
         # mass stays uniform); the unbiased caller passes vmask, whose 1s at
         # real vertices reproduce the scalar base exactly.
-        bz = pl.load(bias_ref, (pl.ds(db, 1), slice(None)))[0, :]
+        new = (base * bias_ref[rows, :] + dmass + d * acc_ref[...]) \
+            * vmask_ref[rows, :]
         # perforation (Alg 5): frozen vertices keep their current rank, so
         # in-pass fresh reads by later dst blocks observe the frozen value.
         # The freeze mask is decided OUTSIDE the kernel (the engine's
         # perforation transform); here it is only respected.
-        fz = pl.load(frozen_ref, (pl.ds(db, 1), slice(None)))[0, :]
-        old = pl.load(pr_ref, (pl.ds(db, 1), slice(None)))[0, :]
-        new = (base * bz + dmass + d * acc_ref[0, :]) * vm
-        new = fz * old + (1.0 - fz) * new
-        pl.store(pr_ref, (pl.ds(db, 1), slice(None)),
-                 new[None, :].astype(pr_ref.dtype))
+        fz = frozen_ref[rows, :]
+        pr_ref[rows, :] = (fz * pr_ref[rows, :] + (1.0 - fz) * new
+                           ).astype(pr_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -193,7 +253,7 @@ def spmv_gs_pass(
     tile_dst_block: jax.Array,  # (T,) int32 — non-decreasing
     *,
     block: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """One full blocked Gauss–Seidel pass; returns the updated rank blocks.
 
@@ -203,8 +263,8 @@ def spmv_gs_pass(
     VMEM-resident ``(n_blocks, block)`` operand, same footprint as
     ``vmask_blocks``).
 
-    ``tiles_weight`` is the per-edge weights VMEM operand (tile layout, one
-    ``(1, cap)`` slice streamed per grid step alongside the index tiles); it
+    ``tiles_weight`` is the per-edge weights operand (tile layout, one
+    ``(1, cap)`` row streamed per grid step alongside the index tiles); it
     scales each edge's gathered contribution inside the one-hot tile matmul.
     ``bias_blocks`` is the per-vertex teleport-bias operand multiplying the
     ``base`` scalar at commit; ``params`` carries ``[base, d, dmass]`` with
@@ -214,33 +274,29 @@ def spmv_gs_pass(
     traffic, and ``val·val = val`` for a {0,1} mask)."""
     n_blocks = pr_blocks.shape[0]
     T, cap = tiles_src_local.shape
+    state = _resident_spec((n_blocks, block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [state] * 5 + [_tile_spec(cap)] * 4,
+        out_specs=state,
         scratch_shapes=[pltpu.VMEM((1, block), jnp.float32)],
     )
+    # five resident inputs + the state, each single-buffered
+    resident = 6 * n_blocks * block * 4
     return pl.pallas_call(
         _spmv_gs_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), pr_blocks.dtype),
-        interpret=interpret,
-    )(tile_src_block, tile_dst_block, params, pr_blocks, inv_out_blocks,
-      vmask_blocks, bias_blocks, frozen_blocks, tiles_src_local,
-      tiles_dst_local, tiles_valid, tiles_weight)
+        compiler_params=_compiler_params(
+            resident, _tile_body_bytes(block, cap, 1)),
+        interpret=pallas_interpret(interpret),
+    )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
+      inv_out_blocks, vmask_blocks, bias_blocks, frozen_blocks,
+      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid),
+      _rows(tiles_weight))
 
 
 # ---------------------------------------------------------------------------
@@ -250,12 +306,12 @@ def spmv_gs_pass(
 # The PPR subsystem solves b personalized rank vectors against ONE graph; the
 # tile structure (and thus the HBM edge traffic) is identical for every row,
 # so the batched pass amortizes the index streams across the whole batch: the
-# same one-hot tile matmuls now contract a (block, b) panel instead of a
-# (block,) vector — still MXU work, b× the useful FLOPs per byte of edge data.
+# same one-hot tile matmuls now contract a (b, block) panel instead of a
+# (1, block) row — still MXU work, b× the useful FLOPs per byte of edge data.
 #
 # Layout: the rank state is (n_blocks, b, block) — block-major so each dst
 # block's (b, block) panel is one contiguous VMEM slice, batch on the sublane
-# axis (compiled TPU prefers b a multiple of 8; interpret mode doesn't care).
+# axis (compiled TPU wants b a multiple of 8; interpret mode doesn't care).
 # As in spmv_gs_pass the state lives in the output ref under a constant index
 # map and is revisited across the whole grid: step 0 copies the input ranks
 # in, each dst-block run accumulates tile panels into a (b, block) VMEM
@@ -267,7 +323,8 @@ def spmv_gs_pass(
 # per pass (the per-row teleport matrix generalizes the scalar (1-d)/n of the
 # global kernel).  ``frozen_rows`` is the batched form of the freeze mask:
 # whole rows (converged serving slots) hold their ranks through the pass —
-# per-slot early exit for the continuous-batching PPR engine.
+# per-slot early exit for the continuous-batching PPR engine.  It enters the
+# kernel as a (b, 1) column so it broadcasts along lanes.
 
 
 def _spmv_gs_multi_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref,
@@ -291,32 +348,18 @@ def _spmv_gs_multi_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     # Fresh gather of the whole batch panel: (b, block) ranks of src block sb.
-    pr_sb = pl.load(pr_ref, (pl.ds(sb, 1), slice(None), slice(None)))[0]
-    inv_sb = pl.load(inv_ref, (pl.ds(sb, 1), slice(None)))[0]
-    contrib = pr_sb * inv_sb[None, :]  # (b, block)
-    block = contrib.shape[-1]
-    ids = jax.lax.broadcasted_iota(jnp.int32, (src_ref.shape[-1], block), 1)
-    onehot_src = (src_ref[0, :][:, None] == ids).astype(jnp.float32)
-    gathered = jnp.dot(onehot_src, contrib.T,
-                       preferred_element_type=jnp.float32)  # (cap, b)
     # validity·weight folds the per-edge weights operand into the panel
     # (unweighted callers pass tiles_valid as wt: val² = val for a {0,1} mask)
-    vals = gathered * (val_ref[0, :] * wt_ref[0, :])[:, None]
-    onehot_dst = (dst_ref[0, :][:, None] == ids).astype(jnp.float32)
-    acc_ref[...] += jnp.dot(vals.T, onehot_dst,
-                            preferred_element_type=jnp.float32)  # (b, block)
+    contrib = pr_ref[sb] * inv_ref[pl.ds(sb, 1), :]  # (b, block)
+    acc_ref[...] += _tile_gather_scatter(src_ref[...], dst_ref[...],
+                                         val_ref[...] * wt_ref[...], contrib)
 
     @pl.when(is_run_end)
     def _commit_block():
-        d = params_ref[0, 0]
-        vm = pl.load(vmask_ref, (pl.ds(db, 1), slice(None)))[0]  # (block,)
-        fz = frozen_ref[0, :]  # (b,) — 1 for rows held through the pass
-        base = pl.load(base_ref, (pl.ds(db, 1), slice(None), slice(None)))[0]
-        old = pl.load(pr_ref, (pl.ds(db, 1), slice(None), slice(None)))[0]
-        new = (base + d * acc_ref[...]) * vm[None, :]
-        new = fz[:, None] * old + (1.0 - fz[:, None]) * new
-        pl.store(pr_ref, (pl.ds(db, 1), slice(None), slice(None)),
-                 new[None].astype(pr_ref.dtype))
+        d = params_ref[0]
+        fz = frozen_ref[...]  # (b, 1) — 1 for rows held through the pass
+        new = (base_ref[db] + d * acc_ref[...]) * vmask_ref[pl.ds(db, 1), :]
+        pr_ref[db] = (fz * pr_ref[db] + (1.0 - fz) * new).astype(pr_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -335,7 +378,7 @@ def spmv_gs_pass_multi(
     tile_dst_block: jax.Array,  # (T,) int32 — non-decreasing
     *,
     block: int,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """One blocked Gauss–Seidel pass over ``b`` rank rows; returns the
     updated ``(n_blocks, b, block)`` state.
@@ -345,38 +388,38 @@ def spmv_gs_pass_multi(
     reduces to the global kernel's scalar base when every row's teleport is
     uniform (per-vertex bias also folds in here: the caller scales the
     teleport rows, so this kernel needs no separate bias operand).
-    ``tiles_weight`` is the per-edge weights VMEM operand shared across the
-    whole batch — one ``(1, cap)`` stream per tile scales the ``(cap, b)``
+    ``tiles_weight`` is the per-edge weights operand shared across the
+    whole batch — one ``(1, cap)`` stream per tile scales the ``(b, cap)``
     gathered panel; unweighted callers pass ``tiles_valid``.  ``frozen_rows``
     freezes whole rows (serving slots), not single vertices; with ``b=1``,
     all-zeros mask and a uniform base this pass is exactly
     :func:`spmv_gs_pass` on one vector."""
     n_blocks, b, _ = pr_blocks.shape
     T, cap = tiles_src_local.shape
+    vertex = _resident_spec((n_blocks, block))
+    panel = _resident_spec((n_blocks, b, block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T,),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, b, block), lambda t, sb, db: (0, 0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, block), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((1, b), lambda t, sb, db: (0, 0)),
-            pl.BlockSpec((n_blocks, b, block), lambda t, sb, db: (0, 0, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-            pl.BlockSpec((1, cap), lambda t, sb, db: (t, 0)),
-        ],
-        out_specs=pl.BlockSpec((n_blocks, b, block), lambda t, sb, db: (0, 0, 0)),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), panel, vertex,
+                  vertex, _resident_spec((b, 1)), panel]
+        + [_tile_spec(cap)] * 4,
+        out_specs=panel,
         scratch_shapes=[pltpu.VMEM((b, block), jnp.float32)],
     )
+    # rank, base and state panels (b padded to 8 sublanes) and the two
+    # per-vertex rows, each single-buffered
+    panel_bytes = n_blocks * -(-b // 8) * 8 * block * 4
+    resident = 3 * panel_bytes + 2 * n_blocks * block * 4
     return pl.pallas_call(
         _spmv_gs_multi_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, b, block), pr_blocks.dtype),
-        interpret=interpret,
-    )(tile_src_block, tile_dst_block, params, pr_blocks, inv_out_blocks,
-      vmask_blocks, frozen_rows, base_blocks, tiles_src_local,
-      tiles_dst_local, tiles_valid, tiles_weight)
+        compiler_params=_compiler_params(
+            resident, _tile_body_bytes(block, cap, b)),
+        interpret=pallas_interpret(interpret),
+    )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
+      inv_out_blocks, vmask_blocks, frozen_rows.reshape(b, 1), base_blocks,
+      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid),
+      _rows(tiles_weight))

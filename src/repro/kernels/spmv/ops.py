@@ -201,7 +201,7 @@ def pagerank_pallas(
     d: float = DEFAULT_DAMPING,
     threshold: float = 1e-8,
     max_iter: int = 10_000,
-    interpret: bool = False,
+    interpret: bool | None = None,
     schedule: str = "barrier",
     handle_dangling: bool = False,
     perforate: bool = False,
@@ -252,7 +252,7 @@ def _build(g, block: int = 256, tile_cap: int = 1024, gain: bool = False, **_):
 
 def _run(schedule, perforate=False):
     def run(b, *, d=DEFAULT_DAMPING, threshold=1e-8, max_iter=10_000,
-            handle_dangling=False, interpret=False, pr0=None, **_):
+            handle_dangling=False, interpret=None, pr0=None, **_):
         return pagerank_pallas(
             b, d=d, threshold=threshold, max_iter=max_iter, interpret=interpret,
             schedule=schedule, handle_dangling=handle_dangling,

@@ -8,20 +8,20 @@ collectives.
 """
 from __future__ import annotations
 
-from jax.sharding import Mesh
-
-from repro.utils.jaxcompat import make_mesh
+import jax
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1) -> Mesh:
     """Small mesh over however many (host) devices exist — tests/benchmarks."""
-    return make_mesh((n_data, n_model), ("data", "model"))
+    return jax.make_mesh((n_data, n_model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def make_serving_mesh(shards: int | None = None, axis: str = "batch") -> Mesh:
@@ -30,11 +30,9 @@ def make_serving_mesh(shards: int | None = None, axis: str = "batch") -> Mesh:
     ``repro.serving.ppr_engine.shard_batch_step``).  ``min(shards, devices)``
     shards, all devices when ``shards`` is None; the engine requires
     ``slots`` divisible by the resulting axis size."""
-    import jax
-
     n_dev = jax.device_count()
     shards = n_dev if shards is None else max(1, min(int(shards), n_dev))
-    return make_mesh((shards,), (axis,))
+    return jax.make_mesh((shards,), (axis,), axis_types=(AxisType.Auto,))
 
 
 def make_solver_mesh(p: int | None = None, axis: str = "data") -> Mesh:

@@ -5,7 +5,9 @@
 
 Variants come from the registry (``repro.core.solver``); ``--list`` prints
 them with their ``layout``/``backend``/``schedule`` metadata columns.  The
-Pallas variants run the kernel in interpret mode off-TPU automatically.
+Pallas variants compile for the TPU, and run in the Pallas interpreter on the
+CPU backend (``repro.utils.platform.pallas_interpret``).  Compiled programs
+are cached across runs (``repro.utils.platform.init_compile_cache``).
 
 Two subcommands expose the personalized-PageRank subsystem:
 
@@ -41,7 +43,7 @@ from repro.core.solver import (
     build_variant, bundle_partitions, get_variant, list_variants, plan_stats,
 )
 from repro.graphs import DATASETS, make_dataset
-from repro.utils.jaxcompat import on_tpu
+from repro.utils.platform import init_compile_cache
 
 
 def _parse_seeds(spec: str) -> tuple[int, ...]:
@@ -294,6 +296,7 @@ def build_main(argv) -> int:
 
 def main(argv=None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
+    init_compile_cache()
     if argv and argv[0] == "query":
         return query_main(argv[1:])
     if argv and argv[0] == "serve":
@@ -325,7 +328,7 @@ def main(argv=None) -> int:
                          "dispatch on — layout (bundle-sharing key: variants "
                          "with the same layout share one build), backend "
                          "(numpy | jax | pallas | shard_map; pallas runs "
-                         "interpreted off-TPU), schedule (barrier | nosync | "
+                         "interpreted on the CPU backend), schedule (barrier | nosync | "
                          "sequential: the cost-model discipline)")
     args = ap.parse_args(argv)
 
@@ -373,7 +376,6 @@ def main(argv=None) -> int:
         tile_cap=args.tile_cap,
         local_sweeps=args.local_sweeps,
         send_fraction=args.send_fraction,
-        interpret=not on_tpu(),
     )
     t0 = time.time()
     v, bundle = build_variant(args.variant, g, **opts)

@@ -321,7 +321,8 @@ def teleport_from_seeds_like(teleport, n: int, n_pad: int) -> np.ndarray:
 def make_batched_pallas_sweep(
     tiles_src_local, tiles_dst_local, tiles_valid, tile_src_block,
     tile_dst_block, inv_out_blocks, dangling_blocks, tiles_weight=None,
-    *, n: int, block: int, d: float, handle_dangling: bool, interpret: bool,
+    *, n: int, block: int, d: float, handle_dangling: bool,
+    interpret: bool | None = None,
 ):
     """``sweep(pr_blocks, tele_blocks, frozen_rows (1,b)) -> new blocks`` —
     one batched Gauss–Seidel pass in the kernel's ``(n_blocks, b, block)``
@@ -406,7 +407,7 @@ def ppr_pallas(
     d: float = DEFAULT_DAMPING,
     threshold: float = 1e-8,
     max_iter: int = 10_000,
-    interpret: bool = False,
+    interpret: bool | None = None,
     handle_dangling: bool = False,
 ) -> PageRankResult:
     """Batched PPR via the multi-vector blocked Gauss–Seidel kernel: all
@@ -454,7 +455,7 @@ def _ppr_nosync_run(b, *, d=DEFAULT_DAMPING, threshold=1e-8, max_iter=10_000,
 
 
 def _ppr_pallas_run(b, *, d=DEFAULT_DAMPING, threshold=1e-8, max_iter=10_000,
-                    handle_dangling=False, seeds=None, interpret=False, **_):
+                    handle_dangling=False, seeds=None, interpret=None, **_):
     return ppr_pallas(b, _tele(b.n, seeds), d=d, threshold=threshold,
                       max_iter=max_iter, interpret=interpret,
                       handle_dangling=handle_dangling)

@@ -48,7 +48,6 @@ from repro.ppr.batched import (
     teleport_from_seeds,
 )
 from repro.ppr.push import topk
-from repro.utils.jaxcompat import on_tpu, shard_map
 
 __all__ = ["PPRQuery", "PPRResponse", "PPREngine", "make_query_stream",
            "shard_batch_step"]
@@ -178,7 +177,6 @@ class _PallasBackend:
         pg = PallasGraph.build(g, block=block, tile_cap=tile_cap)
         self.n = g.n
         self.pg = pg
-        interpret = (not on_tpu()) if interpret is None else interpret
         self.state = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         self.tele = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         sweep = make_batched_pallas_sweep(
@@ -239,7 +237,7 @@ def shard_batch_step(backend, mesh: Mesh, axis: Optional[str] = None):
     bax = backend.BATCH_AXIS
     nd = backend.state.ndim
     spec = P(*[axis if i == bax else None for i in range(nd)])
-    mapped = shard_map(
+    mapped = jax.shard_map(
         backend.multi_step, mesh=mesh,
         in_specs=(spec, spec, P(axis)),
         out_specs=(spec, P(axis)),

@@ -116,10 +116,11 @@ def test_capture_records_grid_without_executing():
                               out_shape=jax.ShapeDtypeStruct((4,), np.float32),
                               interpret=interpret)(n)
 
-    gs, out_shape = capture_grid_spec(
+    gs, out_shape, operands = capture_grid_spec(
         fake_kernel, [jax.ShapeDtypeStruct((4,), np.float32)])
     assert tuple(gs.grid) == (4,)
     assert out_shape.shape == (4,)
+    assert [shape for shape, _ in operands] == [(4,)]
     assert ran  # the wrapper body ran; the kernel itself never compiled
     assert pl.pallas_call is not None  # monkeypatch restored
 
@@ -130,9 +131,7 @@ def test_capture_records_grid_without_executing():
 
 
 def test_jaxpr_flags_float64_leak():
-    from jax.experimental import enable_x64
-
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda x: jnp.sum(x.astype(jnp.float64)))(jnp.ones(4, jnp.float32))
     findings = lint_jaxpr(jaxpr, target="fixture")

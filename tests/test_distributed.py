@@ -25,9 +25,7 @@ from repro.graphs import rmat_graph
 def test_param_specs_divide_on_production_mesh(arch):
     from repro.launch.specs import abstract_train_state
     from repro.sharding.rules import param_specs
-    from repro.utils.jaxcompat import abstract_mesh
-
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config(arch)
     state = abstract_train_state(cfg)
     specs = param_specs(state.params, mesh)
@@ -48,9 +46,7 @@ def test_moe_expert_sharding_fallback():
     sharded; the FFN dim is sharded instead."""
     from repro.launch.specs import abstract_params
     from repro.sharding.rules import param_specs
-    from repro.utils.jaxcompat import abstract_mesh
-
-    mesh = abstract_mesh((16, 16), ("data", "model"))
+    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
     cfg = get_config("mixtral-8x22b")
     specs = param_specs(abstract_params(cfg), mesh)
     wi_spec = specs["layers"]["mlp"]["wi"]
@@ -79,8 +75,8 @@ _DIST_SCRIPT = textwrap.dedent(
     g = rmat_graph(9, avg_degree=6, seed=1)
     ref, _ = pagerank_numpy(g, threshold=1e-12)
     pg = PartitionedGraph.from_graph(g, p=8)
-    from repro.utils.jaxcompat import make_mesh
-    mesh = make_mesh((8,), ("data",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     out = {}
     rb = distributed_pagerank(pg, mesh, mode="barrier", threshold=1e-7)
     out["barrier"] = {"rounds": int(rb.iterations), "l1": l1_norm(rb.pr, ref)}

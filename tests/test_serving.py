@@ -231,11 +231,12 @@ def test_global_entry_invalidated_by_any_update():
     ("pallas", dict(block=16, tile_cap=64, interpret=True)),
 ])
 def test_mesh1_topk_identical_to_unsharded(g64, backend, opts):
-    from repro.utils.jaxcompat import make_mesh
+    import jax
+    from jax.sharding import AxisType
 
     qs = make_query_stream(g64.n, 6, top_k=8, seed=0)
     plain = _engine(g64, backend=backend, **opts).drain(qs)
-    mesh = make_mesh((1,), ("batch",))
+    mesh = jax.make_mesh((1,), ("batch",), axis_types=(AxisType.Auto,))
     sharded = _engine(g64, backend=backend, mesh=mesh, **opts).drain(qs)
     for a, b in zip(sorted(plain, key=lambda r: r.qid),
                     sorted(sharded, key=lambda r: r.qid)):

@@ -53,9 +53,10 @@ def test_build_cell_in_process_small_mesh():
     exist (1 here) — the 512-device path is exercised by launch/dryrun.py."""
     from repro.configs import ShapeSpec
     from repro.launch.specs import build_cell
-    from repro.utils.jaxcompat import make_mesh
+    from jax.sharding import AxisType
 
-    mesh = make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
     cfg = get_config("qwen2-vl-2b").reduced()
     for kind in ("train", "prefill", "decode"):
         shape = ShapeSpec(kind, 64, 4, kind)

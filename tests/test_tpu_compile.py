@@ -1,0 +1,67 @@
+"""The SpMV kernels compile for a described TPU v5e at the chip smoke's
+widths — what interpret mode cannot check (block alignment, VMEM, SMEM,
+2-D contractions) is checked here without a chip.
+
+The topology is described inside a module fixture, never at import: one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.spmv.kernel import spmv_blocked, spmv_gs_pass, spmv_gs_pass_multi
+
+# chip_smoke.py's layouts: full webStanford at block 1024 / cap 128, and
+# full socEpinions1 at the serving defaults (block 256 / cap 1024, 8 slots)
+WEB = dict(n_blocks=276, block=1024, T=76_371, cap=128)
+SOC = dict(n_blocks=297, block=256, T=84_415, cap=1024, b=8)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _args(sharding, *, n_blocks, block, T, cap, b=None):
+    def s(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    vertex = s((n_blocks, block))
+    tiles = (s((T, cap), jnp.int32), s((T, cap), jnp.int32), s((T, cap)), s((T, cap)))
+    maps = (s((T,), jnp.int32), s((T,), jnp.int32))
+    if b is None:
+        return {
+            "spmv_blocked": (vertex,) + tiles[:3] + maps,
+            "spmv_gs_pass": (vertex,) * 5 + (s((1, 3)),) + tiles + maps,
+        }
+    panel = s((n_blocks, b, block))
+    return {"spmv_gs_pass_multi":
+            (panel, vertex, vertex, s((1, b)), panel, s((1, 1))) + tiles + maps}
+
+
+@pytest.mark.parametrize("kernel,fn,widths", [
+    ("spmv_blocked", spmv_blocked, WEB),
+    ("spmv_gs_pass", spmv_gs_pass, WEB),
+    ("spmv_gs_pass_multi", spmv_gs_pass_multi, SOC),
+])
+def test_kernel_compiles_for_v5e(one_chip, kernel, fn, widths):
+    args = _args(one_chip, **widths)[kernel]
+    compiled = jax.jit(
+        lambda *a: fn(*a, block=widths["block"], interpret=False)
+    ).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
