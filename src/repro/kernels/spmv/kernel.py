@@ -31,6 +31,8 @@ whole-pass state is one full ``(n_blocks, block)`` block addressed by row
 Grid: one step per tile, tiles sorted by dst_block → each output block is
 resident in VMEM for one contiguous run of grid steps (standard Pallas
 reduction/revisiting pattern, initialized via ``pl.when`` on run start).
+The Gauss–Seidel wrappers record their grid size in
+:data:`repro.utils.tracing.GRID_STEPS` when they trace.
 Scalar-prefetched tile→block maps drive the BlockSpec index maps.
 """
 from __future__ import annotations
@@ -43,6 +45,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.utils.platform import pallas_interpret
+from repro.utils.tracing import GRID_STEPS
 
 # one-hot entries are exact in bf16 but the ranks are not: contract in f32
 _PRECISION = jax.lax.Precision.HIGHEST
@@ -274,6 +277,7 @@ def spmv_gs_pass(
     traffic, and ``val·val = val`` for a {0,1} mask)."""
     n_blocks = pr_blocks.shape[0]
     T, cap = tiles_src_local.shape
+    GRID_STEPS["spmv_gs_pass"] = T
     state = _resident_spec((n_blocks, block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -396,6 +400,7 @@ def spmv_gs_pass_multi(
     :func:`spmv_gs_pass` on one vector."""
     n_blocks, b, _ = pr_blocks.shape
     T, cap = tiles_src_local.shape
+    GRID_STEPS["spmv_gs_pass_multi"] = T
     vertex = _resident_spec((n_blocks, block))
     panel = _resident_spec((n_blocks, b, block))
 

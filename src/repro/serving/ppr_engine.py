@@ -12,8 +12,10 @@ active slot at once:
 * **step** — one jitted call runs ``iters_per_step`` batched sweeps; frozen
   rows (free slots and already-converged ones) are held in place, which is
   the engine-level form of the batched solver's :func:`row_freeze` per-row
-  early exit.  Per-row errors come back with the state, so the scheduler
-  sees convergence without an extra device round-trip.
+  early exit.  Each sweep's per-row change comes back with the state, so the
+  scheduler sees convergence without an extra device round-trip; the last
+  sweep's decides the harvest, the earlier ones say at which sweep a row
+  first converged.
 * **harvest** — a converged slot's row is pulled to host once, top-k
   extracted (ties broken by vertex id), the vector cached, and the slot
   recycled for the next queued query.
@@ -23,6 +25,9 @@ vertex-centric sweep (:func:`repro.ppr.batched.make_batched_sweep`),
 ``"pallas"`` the multi-vector blocked Gauss–Seidel kernel
 (:func:`repro.kernels.spmv.spmv_gs_pass_multi`) with the rank batch living
 in VMEM across each pass.
+
+Each step is a ``ppr.step`` span holding ``ppr.dispatch``, ``ppr.sync`` and
+one ``ppr.harvest`` per converged slot (:mod:`repro.utils.tracing`).
 """
 from __future__ import annotations
 
@@ -48,6 +53,7 @@ from repro.ppr.batched import (
     teleport_from_seeds,
 )
 from repro.ppr.push import topk
+from repro.utils.tracing import span
 
 __all__ = ["PPRQuery", "PPRResponse", "PPREngine", "make_query_stream",
            "shard_batch_step"]
@@ -124,7 +130,20 @@ class _Active:
     warm: bool = False
 
 
-class _JaxBackend:
+class _Backend:
+    """What both compute backends share: one engine step.  A backend's
+    ``multi_step(pr, tele, frozen)`` runs ``iters_per_step`` sweeps and
+    returns the state and each sweep's per-row change, ``(iters, B)``."""
+
+    def step(self, frozen: np.ndarray) -> np.ndarray:
+        with span("ppr.dispatch"):
+            self.state, errs = self._multi_step(self.state, self.tele,
+                                                jnp.asarray(frozen))
+        with span("ppr.sync"):
+            return np.asarray(errs)
+
+
+class _JaxBackend(_Backend):
     """(B, n) rank batch advanced by the batched vertex-centric sweep."""
 
     BATCH_AXIS = 0  # slot axis of `state`/`tele` — the mesh-sharded axis
@@ -140,13 +159,10 @@ class _JaxBackend:
         self.tele = jnp.zeros((slots, g.n), jnp.float32)
 
         def multi_step(pr, tele, frozen):
-            def body(_, carry):
-                pr, _ = carry
+            def body(pr, _):
                 new = jnp.where(frozen[:, None], pr, sweep(pr, tele))
                 return new, jnp.max(jnp.abs(new - pr), axis=1)
-            return jax.lax.fori_loop(
-                0, iters_per_step, body,
-                (pr, jnp.full((pr.shape[0],), jnp.inf, jnp.float32)))
+            return jax.lax.scan(body, pr, length=iters_per_step)
 
         # unjitted: the mesh wrapper and the jaxpr lint both need the raw fn
         self.multi_step = multi_step
@@ -159,13 +175,8 @@ class _JaxBackend:
     def get_row(self, slot: int) -> np.ndarray:
         return np.asarray(self.state[slot], dtype=np.float64)
 
-    def step(self, frozen: np.ndarray) -> np.ndarray:
-        self.state, err = self._multi_step(self.state, self.tele,
-                                           jnp.asarray(frozen))
-        return np.asarray(err)
 
-
-class _PallasBackend:
+class _PallasBackend(_Backend):
     """(n_blocks, B, block) rank batch advanced by the multi-vector GS pass."""
 
     BATCH_AXIS = 1  # slot axis of the (n_blocks, B, block) state
@@ -188,13 +199,10 @@ class _PallasBackend:
         def multi_step(pr, tele, frozen):
             fz = frozen.astype(jnp.float32).reshape(1, -1)
 
-            def body(_, carry):
-                pr, _ = carry
+            def body(pr, _):
                 new = sweep(pr, tele, fz)
                 return new, jnp.max(jnp.abs(new - pr), axis=(0, 2))
-            return jax.lax.fori_loop(
-                0, iters_per_step, body,
-                (pr, jnp.full((pr.shape[1],), jnp.inf, jnp.float32)))
+            return jax.lax.scan(body, pr, length=iters_per_step)
 
         # unjitted: the mesh wrapper and the jaxpr lint both need the raw fn
         self.multi_step = multi_step
@@ -211,11 +219,6 @@ class _PallasBackend:
     def get_row(self, slot: int) -> np.ndarray:
         return np.asarray(self.state[:, slot, :],
                           dtype=np.float64).reshape(-1)[:self.n]
-
-    def step(self, frozen: np.ndarray) -> np.ndarray:
-        self.state, err = self._multi_step(self.state, self.tele,
-                                           jnp.asarray(frozen))
-        return np.asarray(err)
 
 
 _BACKENDS = {"jax": _JaxBackend, "pallas": _PallasBackend}
@@ -240,7 +243,7 @@ def shard_batch_step(backend, mesh: Mesh, axis: Optional[str] = None):
     mapped = jax.shard_map(
         backend.multi_step, mesh=mesh,
         in_specs=(spec, spec, P(axis)),
-        out_specs=(spec, P(axis)),
+        out_specs=(spec, P(None, axis)),
         check_vma=False,
     )
     backend._multi_step = jax.jit(mapped)
@@ -302,6 +305,9 @@ class PPREngine:
         self.submit_rejections = 0
         self.busy_slot_steps = 0
         self.total_slot_steps = 0
+        # queries waiting to take a slot as one frees: set by the serving
+        # runtime around its steps, recorded on each step's span
+        self.queued = 0
         # fired with the GraphDelta after every applied update batch — the
         # serving runtime hangs its result-cache invalidation here
         self.update_callbacks: list = []
@@ -350,9 +356,8 @@ class PPREngine:
         """Admit ``q`` into a free slot; False when the batch is full.
         Raises on malformed seeds without mutating engine state."""
         self.validate(q)
-        try:
-            slot = self._active.index(None)
-        except ValueError:
+        slot = self.free_slot()
+        if slot is None:
             self.submit_rejections += 1
             return False
         # the subsystem-wide bias convention (repro.ppr.batched.bias_scaled):
@@ -370,34 +375,59 @@ class PPREngine:
         self._frozen[slot] = False
         return True
 
+    def free_slot(self) -> Optional[int]:
+        """The slot the next :meth:`submit` takes; None when the batch is
+        full."""
+        try:
+            return self._active.index(None)
+        except ValueError:
+            return None
+
     def step(self) -> list[PPRResponse]:
         """Advance every active slot ``iters_per_step`` sweeps; harvest and
         recycle the slots that converged."""
         if all(a is None for a in self._active):
             return []
-        self.busy_slot_steps += self.active_count
-        self.total_slot_steps += self.slots
-        err = self._backend.step(self._frozen)
+        active = self.active_count
+        with span("ppr.step", step=self.total_slot_steps // self.slots,
+                  active=active, slots=self.slots, queued=self.queued) as sp:
+            self.busy_slot_steps += active
+            self.total_slot_steps += self.slots
+            errs = self._backend.step(self._frozen)
+            out = self._harvest(errs)
+            sp.set_metadata(active_after=self.active_count)
+        return out
+
+    def _harvest(self, errs: np.ndarray) -> list[PPRResponse]:
+        """Harvest the slots whose last sweep changed no score by more than
+        the threshold; ``errs`` is each sweep's per-row change."""
         out: list[PPRResponse] = []
         for slot, act in enumerate(self._active):
             if act is None:
                 continue
+            sweeps_before = act.iters
             act.iters += self.iters_per_step
-            if err[slot] <= self.threshold:
+            if errs[-1, slot] > self.threshold:
+                continue
+            # the first sweep of this step after which the row had converged
+            converged = sweeps_before + 1 + int(
+                np.argmax(errs[:, slot] <= self.threshold))
+            with span("ppr.harvest", qid=act.query.qid, sweeps=act.iters,
+                      converged_sweep=converged, warm=act.warm):
                 row = self._backend.get_row(slot)
                 idx, vals = topk(row, act.query.top_k)
-                key = self._cache_key(act.query)
-                self._cache[key] = row
-                self._cache.move_to_end(key)
-                while len(self._cache) > self._cache_size:
-                    self._cache.popitem(last=False)
-                out.append(PPRResponse(
-                    qid=act.query.qid, seeds=tuple(act.query.seeds),
-                    indices=idx, values=vals, iterations=act.iters,
-                    latency_s=time.perf_counter() - act.t0,
-                    warm_start=act.warm))
-                self._active[slot] = None
-                self._frozen[slot] = True
+            key = self._cache_key(act.query)
+            self._cache[key] = row
+            self._cache.move_to_end(key)
+            while len(self._cache) > self._cache_size:
+                self._cache.popitem(last=False)
+            out.append(PPRResponse(
+                qid=act.query.qid, seeds=tuple(act.query.seeds),
+                indices=idx, values=vals, iterations=act.iters,
+                latency_s=time.perf_counter() - act.t0,
+                warm_start=act.warm))
+            self._active[slot] = None
+            self._frozen[slot] = True
         return out
 
     @property

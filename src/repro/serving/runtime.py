@@ -37,11 +37,12 @@ into a production-shaped queueing system:
   (host scheduling is unchanged), and a 1-device mesh is bit-identical to
   the unsharded path.
 
-* **Metrics** — every stage reports into a
-  :class:`repro.serving.metrics.ServingMetrics` bag (admit/solve/harvest
-  timers, queue-depth + slot-occupancy gauges, offered/completed/rejected/
-  expired/cache counters) that the launcher summary and
-  ``benchmarks/bench_ppr.py``'s closed-loop records both print.
+* **Tracing** — counters (offered/admitted/completed/rejected/expired/
+  cache) and the queue-depth gauge live in a
+  :class:`repro.utils.tracing.ServingMetrics` bag that the launcher summary
+  and the closed-loop load generator read; each offer, admission and
+  result-cache insertion is a span on the profiler's clock
+  (:func:`repro.utils.tracing.span`), nested around the engine's own.
 """
 from __future__ import annotations
 
@@ -52,8 +53,8 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from repro.serving.metrics import ServingMetrics
 from repro.serving.ppr_engine import PPREngine, PPRQuery, PPRResponse
+from repro.utils.tracing import ServingMetrics, span
 
 __all__ = ["Admission", "QueueEntry", "ServingRuntime"]
 
@@ -108,9 +109,9 @@ class ServingRuntime:
     """Queueing front-end over a :class:`PPREngine` (see module docstring).
 
     ``clock`` is injectable (default ``time.perf_counter``) so tests and the
-    virtual-time load generator can drive deadlines deterministically;
-    stage *timers* always use real wall time — they measure host cost, not
-    simulated time.
+    virtual-time load generator can drive deadlines deterministically; the
+    ``queue_ms`` of a ``ppr.admit`` span is read on it, while spans
+    themselves are timed by the profiler.
     """
 
     def __init__(self, engine: PPREngine, *, queue_depth: int = 64,
@@ -153,6 +154,12 @@ class ServingRuntime:
         caller, which is what lets a closed-loop client measure its own
         backpressure."""
         self.engine.validate(q)
+        with span("ppr.offer", qid=q.qid) as sp:
+            adm = self._offer(q, deadline_s)
+            sp.set_metadata(outcome=adm.status)
+        return adm
+
+    def _offer(self, q: PPRQuery, deadline_s: Optional[float]) -> Admission:
         self.metrics.incr("offered")
         cached = self._results.get(self._result_key(q))
         if cached is not None:
@@ -183,43 +190,39 @@ class ServingRuntime:
         completed this turn."""
         eng = self.engine
         now = self.clock()
-        t0 = time.perf_counter()
-        admitted = 0
         while self._queue and eng.active_count < eng.slots:
             entry = self._queue.popleft()
             if entry.expired(now):
                 self.metrics.incr("expired")
                 continue
-            if not eng.submit(entry.query):
-                # unreachable by the active_count guard, but never inside an
-                # assert: under `python -O` that would silently drop the
-                # already-popped entry
-                raise RuntimeError(
-                    "engine refused a submit despite a free slot")
+            warm_hits = eng.warm_hits
+            with span("ppr.admit", qid=entry.query.qid, slot=eng.free_slot(),
+                      queue_ms=1e3 * (now - entry.t_offer)) as sp:
+                if not eng.submit(entry.query):
+                    # unreachable by the active_count guard, but never inside
+                    # an assert: under `python -O` that would silently drop
+                    # the already-popped entry
+                    raise RuntimeError(
+                        "engine refused a submit despite a free slot")
+                sp.set_metadata(warm=eng.warm_hits > warm_hits)
             self.metrics.incr("admitted")
-            admitted += 1
-        if admitted:
-            self.metrics.timers["admit"].add(time.perf_counter() - t0)
         self.metrics.gauges["queue_depth"].sample(len(self._queue))
-        self.metrics.gauges["slot_occupancy"].sample(
-            eng.active_count / eng.slots)
         if not eng.active_count:
             return []
-        t0 = time.perf_counter()
+        eng.queued = len(self._queue)
         responses = eng.step()
-        self.metrics.timers["solve"].add(time.perf_counter() - t0)
+        eng.queued = 0
         if responses:
-            t0 = time.perf_counter()
-            for r in responses:
-                key = (self.engine._cache_key(
-                    PPRQuery(qid=r.qid, seeds=r.seeds)), len(r.indices))
-                self._results[key] = (r.indices, r.values, r.seeds)
-                self._results.move_to_end(key)
-                while len(self._results) > self._results_size:
-                    self._results.popitem(last=False)
-                    self.metrics.incr("cache_evictions")
+            with span("ppr.cache_insert", n=len(responses)):
+                for r in responses:
+                    key = (self.engine._cache_key(
+                        PPRQuery(qid=r.qid, seeds=r.seeds)), len(r.indices))
+                    self._results[key] = (r.indices, r.values, r.seeds)
+                    self._results.move_to_end(key)
+                    while len(self._results) > self._results_size:
+                        self._results.popitem(last=False)
+                        self.metrics.incr("cache_evictions")
             self.metrics.incr("completed", len(responses))
-            self.metrics.timers["harvest"].add(time.perf_counter() - t0)
         return responses
 
     @property
@@ -341,21 +344,3 @@ class ServingRuntime:
         cbs = self.engine.update_callbacks
         if self._invalidate in cbs:
             cbs.remove(self._invalidate)
-
-    def stats(self) -> dict:
-        """The structured metrics dict the launcher and benchmarks print:
-        runtime metrics plus the engine's own counters."""
-        eng = self.engine
-        return {
-            "backend": eng.backend_name,
-            "slots": eng.slots,
-            "mesh_shards": (eng.mesh.devices.size
-                            if eng.mesh is not None else 1),
-            "queue_depth_limit": self.queue_depth,
-            "result_cache": {"len": len(self._results),
-                             "limit": self._results_size},
-            "warm_hits": eng.warm_hits,
-            "submit_rejections": eng.submit_rejections,
-            "slot_occupancy": eng.slot_occupancy,
-            **self.metrics.to_dict(),
-        }
