@@ -986,21 +986,16 @@ def tile_occupancy_stats(n_edges: int, n_tiles: int, tile_cap: int) -> dict:
     }
 
 
-def blocked_tile_stats(g: Graph, block: int = 256, tile_cap: int = 1024,
-                       chunk_edges: int = 1 << 20) -> dict:
-    """Streaming :class:`BlockedCOO` occupancy — **without building tiles**.
+def block_pair_counts(g: Graph, block: int, chunk_edges: int = 1 << 20
+                      ) -> tuple[np.ndarray, np.ndarray]:
+    """The graph's ``(dst_block, src_block)`` edge histogram at ``block``:
+    the sorted keys ``dst_block · n_blocks + src_block`` of the non-empty
+    pairs and their edge counts.
 
-    One pass over :meth:`Graph.edge_chunks` counts edges per
-    ``(dst_block, src_block)`` bucket; the tile count is then
-    ``Σ ceil(count / tile_cap)`` plus one coverage tile per dst block no
-    bucket touched (``build_blocked_coo`` emits those so the kernel
-    initializes every output run).  Peak memory is O(chunk_edges + distinct
-    buckets), so the layout stage of the out-of-core pipeline can derive
-    occupancy for stores far larger than RAM."""
+    One pass over :meth:`Graph.edge_chunks`; a chunk contributes at most
+    its distinct pairs, folded together at the end, so peak memory stays
+    O(chunk_edges + pairs) for stores far larger than RAM."""
     n_blocks = -(-g.n // block)
-    # per-chunk (bucket, count) summaries, folded together vectorized at the
-    # end — a chunk contributes at most its distinct buckets, so the resident
-    # footprint is far below one row per edge
     key_parts: list[np.ndarray] = []
     cnt_parts: list[np.ndarray] = []
     for _, src, dst, _ in g.edge_chunks(chunk_edges):
@@ -1008,15 +1003,33 @@ def blocked_tile_stats(g: Graph, block: int = 256, tile_cap: int = 1024,
         uniq, cnt = np.unique(bucket, return_counts=True)
         key_parts.append(uniq)
         cnt_parts.append(cnt)
-    if key_parts:
-        keys, inv = np.unique(np.concatenate(key_parts), return_inverse=True)
-        counts = np.zeros(keys.shape[0], dtype=np.int64)
-        np.add.at(counts, inv, np.concatenate(cnt_parts))
-    else:
-        keys = counts = np.zeros(0, dtype=np.int64)
-    n_tiles = int((-(-counts // tile_cap)).sum())
+    if not key_parts:
+        return np.zeros(0, np.int64), np.zeros(0, np.int64)
+    keys, inv = np.unique(np.concatenate(key_parts), return_inverse=True)
+    counts = np.zeros(keys.shape[0], dtype=np.int64)
+    np.add.at(counts, inv, np.concatenate(cnt_parts))
+    return keys, counts
+
+
+def tiles_from_counts(keys: np.ndarray, counts: np.ndarray, n_blocks: int,
+                      tile_cap: int) -> int:
+    """The tiles :func:`build_blocked_coo` makes from a block-pair histogram
+    (:func:`block_pair_counts`): ``ceil(count / tile_cap)`` per pair, plus
+    one coverage tile per dst block no pair touches (the kernel initializes
+    every output run)."""
     covered = np.unique(keys // n_blocks).shape[0]
-    n_tiles += n_blocks - covered  # coverage tiles for empty dst blocks
+    return int((-(-counts // tile_cap)).sum()) + n_blocks - covered
+
+
+def blocked_tile_stats(g: Graph, block: int = 256, tile_cap: int = 1024,
+                       chunk_edges: int = 1 << 20) -> dict:
+    """Streaming :class:`BlockedCOO` occupancy — **without building tiles**,
+    from the block-pair histogram (:func:`block_pair_counts`), so the layout
+    stage of the out-of-core pipeline can derive occupancy for stores far
+    larger than RAM."""
+    n_blocks = -(-g.n // block)
+    keys, counts = block_pair_counts(g, block, chunk_edges)
+    n_tiles = tiles_from_counts(keys, counts, n_blocks, tile_cap)
     stats = tile_occupancy_stats(g.m, n_tiles, tile_cap)
     stats.update(block=block, n_blocks=n_blocks, n_buckets=int(keys.shape[0]))
     return stats

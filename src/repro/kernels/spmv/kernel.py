@@ -51,7 +51,11 @@ from repro.utils.tracing import GRID_STEPS
 _PRECISION = jax.lax.Precision.HIGHEST
 # v5e's default scoped VMEM limit, and the chip's physical VMEM per core
 _DEFAULT_SCOPED_VMEM = 16 * 2**20
-_VMEM_CAPACITY = 128 * 2**20
+VMEM_CAPACITY = 128 * 2**20
+# the most tiles one call takes: the two int32 tile->block maps are
+# scalar-prefetched into 1 MiB of SMEM.  The v5e compiler accepts 130,048
+# tiles and refuses 130,049 (tests/test_tpu_compile.py)
+MAX_TILES = 130_048
 
 
 def _onehot(local_ids, block: int):
@@ -93,16 +97,29 @@ def _resident_spec(shape):
                         pipeline_mode=pl.Buffered(1))
 
 
-def _compiler_params(resident_bytes: int, step_bytes: int):
+def _vmem_need(resident_bytes: int, step_bytes: int) -> int:
+    """The scoped VMEM a kernel asks for: the single-buffered resident
+    state, two buffers of every per-step block, and room for the tile
+    body's ``(block, cap)`` one-hot temporaries."""
+    return resident_bytes + 2 * step_bytes + (4 << 20)
+
+
+def multi_pass_vmem_bytes(n_blocks: int, block: int, cap: int, b: int) -> int:
+    """The scoped VMEM :func:`spmv_gs_pass_multi` asks for at a layout: the
+    rank, base and state panels (``b`` padded to 8 sublanes) and the two
+    per-vertex rows, each single-buffered, plus the tile body."""
+    panel_bytes = n_blocks * -(-b // 8) * 8 * block * 4
+    resident = 3 * panel_bytes + 2 * n_blocks * block * 4
+    return _vmem_need(resident, _tile_body_bytes(block, cap, b))
+
+
+def _compiler_params(need: int):
     """Sequential grid (the Gauss–Seidel order and the output runs depend on
-    it) and a scoped-VMEM limit sized from the operands: the single-buffered
-    resident state, two buffers of every per-step block, and room for the
-    tile body's ``(block, cap)`` one-hot temporaries."""
-    need = resident_bytes + 2 * step_bytes + (4 << 20)
-    if need > _VMEM_CAPACITY:
+    it) and a scoped-VMEM limit of ``need`` bytes (:func:`_vmem_need`)."""
+    if need > VMEM_CAPACITY:
         raise ValueError(
             f"the kernel needs ~{need / 2**20:.0f} MiB of VMEM, more than a "
-            f"TPU core has ({_VMEM_CAPACITY >> 20} MiB); shard the vertex "
+            f"TPU core has ({VMEM_CAPACITY >> 20} MiB); shard the vertex "
             f"space first (repro.core.distributed)")
     return pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
@@ -156,8 +173,8 @@ def spmv_blocked(
         _spmv_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, 1, block), contrib_blocks.dtype),
-        compiler_params=_compiler_params(
-            0, 2 * 4 * 8 * block + _tile_body_bytes(block, cap, 1)),
+        compiler_params=_compiler_params(_vmem_need(
+            0, 2 * 4 * 8 * block + _tile_body_bytes(block, cap, 1))),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, contrib_blocks.reshape(n_blocks, 1, block),
       _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid))
@@ -295,7 +312,7 @@ def spmv_gs_pass(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), pr_blocks.dtype),
         compiler_params=_compiler_params(
-            resident, _tile_body_bytes(block, cap, 1)),
+            _vmem_need(resident, _tile_body_bytes(block, cap, 1))),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
       inv_out_blocks, vmask_blocks, bias_blocks, frozen_blocks,
@@ -413,16 +430,12 @@ def spmv_gs_pass_multi(
         out_specs=panel,
         scratch_shapes=[pltpu.VMEM((b, block), jnp.float32)],
     )
-    # rank, base and state panels (b padded to 8 sublanes) and the two
-    # per-vertex rows, each single-buffered
-    panel_bytes = n_blocks * -(-b // 8) * 8 * block * 4
-    resident = 3 * panel_bytes + 2 * n_blocks * block * 4
     return pl.pallas_call(
         _spmv_gs_multi_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, b, block), pr_blocks.dtype),
         compiler_params=_compiler_params(
-            resident, _tile_body_bytes(block, cap, b)),
+            multi_pass_vmem_bytes(n_blocks, block, cap, b)),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
       inv_out_blocks, vmask_blocks, frozen_rows.reshape(b, 1), base_blocks,

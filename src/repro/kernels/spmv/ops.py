@@ -44,10 +44,89 @@ from repro.core.solver import (
     register_variant,
     solve,
 )
-from repro.graphs.csr import Graph, build_blocked_coo, inv_out_and_dangling
-from repro.kernels.spmv.kernel import spmv_blocked, spmv_gs_pass
+from repro.graphs.csr import (
+    Graph,
+    block_pair_counts,
+    build_blocked_coo,
+    inv_out_and_dangling,
+    tiles_from_counts,
+)
+from repro.kernels.spmv.kernel import (
+    MAX_TILES,
+    VMEM_CAPACITY,
+    multi_pass_vmem_bytes,
+    spmv_blocked,
+    spmv_gs_pass,
+)
+from repro.utils.tracing import LAYOUTS
 
 SCHEDULES = ("barrier", "nosync", "adaptive")
+
+# Device time of one grid step of spmv_gs_pass_multi on a TPU v5 lite:
+# STEP_US + STEP_US_PER_LANE · block · tile_cap, least squares over 12
+# lane-dense layouts (blocks 256–4096, caps 128–1024) of the Graph500
+# scale-16 graph at 8 rows, worst residual 3.2%; spmv_gs_pass at one row
+# costs 0.1-7% more (scripts/tile_step_cost.py; PERF.md, section 6)
+STEP_US = 0.128
+STEP_US_PER_LANE = 6.0e-6
+# the layouts choose_layout weighs: blocks of whole 128-lane rows, and caps
+# a multiple of 128 (a narrower cap pays for 128 lanes: PERF.md, section 6)
+_BLOCKS = range(128, 4096 + 1, 128)
+_CAPS = (128, 256, 512, 1024)
+
+
+def step_us(block: int, tile_cap: int) -> float:
+    """Predicted device time of one grid step at a layout (µs)."""
+    return STEP_US + STEP_US_PER_LANE * block * tile_cap
+
+
+def choose_layout(g: Graph, rows: int = 8) -> tuple[int, int]:
+    """The ``(block, tile_cap)`` whose sweep the cost model predicts
+    fastest: least ``tiles(block, cap) × step_us(block, cap)`` over blocks a
+    multiple of 128 up to 4096 and caps in 128–1024, counted from the
+    graph's ``(dst_block, src_block)`` histogram without building tiles.
+
+    A grid step costs what its ``(block, cap)`` one-hot costs whether its
+    lanes hold edges or padding, so the layout that wins is the one whose
+    tiles are well filled at the smallest area.  Only layouts one kernel
+    call takes are weighed: at most ``MAX_TILES`` tiles (the SMEM tile
+    maps) and the VMEM of :func:`~repro.kernels.spmv.kernel.spmv_gs_pass_multi`
+    at ``rows`` rows within a core's.  A function of the graph and ``rows``
+    alone; ties go to the smaller block, then the smaller cap."""
+    best_cost, best = float("inf"), None
+    for block in _BLOCKS:
+        n_blocks = -(-g.n // block)
+        caps = [c for c in _CAPS if multi_pass_vmem_bytes(
+            n_blocks, block, c, rows) <= VMEM_CAPACITY]
+        # a layout makes at least one tile per dst block and per cap edges
+        floor = {c: max(n_blocks, -(-g.m // c)) for c in caps}
+        caps = [c for c in caps if floor[c] <= MAX_TILES
+                and floor[c] * step_us(block, c) < best_cost]
+        if caps:
+            keys, counts = block_pair_counts(g, block)
+            for cap in caps:
+                tiles = tiles_from_counts(keys, counts, n_blocks, cap)
+                cost = tiles * step_us(block, cap)
+                if tiles <= MAX_TILES and cost < best_cost:
+                    best_cost, best = cost, (block, cap)
+        if n_blocks == 1:  # larger blocks only add padding
+            break
+    if best is None:
+        raise ValueError(
+            f"no tile layout of a {g.n:,}-vertex, {g.m:,}-edge graph fits "
+            f"one kernel call ({MAX_TILES:,} tiles, {VMEM_CAPACITY >> 20} "
+            f"MiB of VMEM at {rows} rows); shard the vertex space first")
+    return best
+
+
+class TileLayout(NamedTuple):
+    """The tile layout a :class:`PallasGraph` was built with."""
+
+    block: int
+    tile_cap: int
+    tiles: int
+    fill: float  # edges / (tiles · tile_cap): one-hot lanes that hold an edge
+    chosen: bool = False  # picked by choose_layout, not passed
 
 
 class PallasGraph(NamedTuple):
@@ -70,6 +149,7 @@ class PallasGraph(NamedTuple):
     tiles_weight: jax.Array | None = None  # (T, cap) per-edge weights
     bias_blocks: jax.Array | None = None  # (n_blocks, block) base multiplier
     gain: jax.Array | None = None  # (n_blocks, n_blocks) cross-block gain
+    layout: TileLayout | None = None
 
     @classmethod
     def build(cls, g: Graph, block: int = 256, tile_cap: int = 1024,
@@ -107,6 +187,8 @@ class PallasGraph(NamedTuple):
                           else jnp.asarray(b.tiles_weight)),
             bias_blocks=bias_blocks,
             gain=gain_mat,
+            layout=TileLayout(block, tile_cap, b.num_tiles,
+                              g.m / max(1, b.num_tiles * tile_cap)),
         )
 
 
@@ -225,6 +307,8 @@ def pagerank_pallas(
         return PageRankResult(jnp.zeros((0,), jnp.float32),
                               jnp.asarray(0, jnp.int32),
                               jnp.asarray(0.0, jnp.float32))
+    if schedule != "barrier" and pg.layout is not None:
+        LAYOUTS["spmv_gs_pass"] = pg.layout._asdict()
     warm = None
     if pr0 is not None:
         padded = np.zeros(pg.n_blocks * pg.block, dtype=np.float32)

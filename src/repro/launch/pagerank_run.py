@@ -226,6 +226,11 @@ def serve_main(argv) -> int:
     print(f"slots: occupancy={eng.slot_occupancy:.0%} "
           f"submit_rejections={eng.submit_rejections} "
           f"(re-queued, not dropped)  {runtime.metrics.summary()}")
+    if eng.layout is not None:
+        lay = eng.layout
+        print(f"layout: block={lay.block} tile_cap={lay.tile_cap} "
+              f"tiles={lay.tiles} fill={lay.fill:.3f} "
+              f"({'chosen' if lay.chosen else 'passed'})")
     return 0
 
 

@@ -44,7 +44,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.pagerank import DeviceGraph
 from repro.core.solver import DEFAULT_DAMPING
 from repro.graphs.csr import Graph
-from repro.kernels.spmv.ops import PallasGraph
+from repro.kernels.spmv.ops import PallasGraph, TileLayout, choose_layout
 from repro.ppr.batched import (
     bias_scaled,
     blocked_rows,
@@ -53,7 +53,7 @@ from repro.ppr.batched import (
     teleport_from_seeds,
 )
 from repro.ppr.push import topk
-from repro.utils.tracing import span
+from repro.utils.tracing import LAYOUTS, span
 
 __all__ = ["PPRQuery", "PPRResponse", "PPREngine", "make_query_stream",
            "shard_batch_step"]
@@ -177,17 +177,28 @@ class _JaxBackend(_Backend):
 
 
 class _PallasBackend(_Backend):
-    """(n_blocks, B, block) rank batch advanced by the multi-vector GS pass."""
+    """(n_blocks, B, block) rank batch advanced by the multi-vector GS pass.
+
+    With neither ``block`` nor ``tile_cap`` passed, the layout is the one
+    :func:`repro.kernels.spmv.ops.choose_layout` picks from the graph's
+    block-pair histogram; a passed one is honoured as given, the other
+    taking its old default (256, 1024)."""
 
     BATCH_AXIS = 1  # slot axis of the (n_blocks, B, block) state
 
     def __init__(self, g: Graph, *, slots: int, d: float,
                  handle_dangling: bool, iters_per_step: int,
-                 block: int = 256, tile_cap: int = 1024,
+                 block: Optional[int] = None, tile_cap: Optional[int] = None,
                  interpret: Optional[bool] = None):
-        pg = PallasGraph.build(g, block=block, tile_cap=tile_cap)
+        chosen = block is None and tile_cap is None
+        if chosen:
+            block, tile_cap = choose_layout(g, rows=slots)
+        pg = PallasGraph.build(g, block=256 if block is None else block,
+                               tile_cap=1024 if tile_cap is None else tile_cap)
         self.n = g.n
         self.pg = pg
+        self.layout = pg.layout._replace(chosen=chosen)
+        LAYOUTS["spmv_gs_pass_multi"] = self.layout._asdict()
         self.state = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         self.tele = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         sweep = make_batched_pallas_sweep(
@@ -262,7 +273,8 @@ class PPREngine:
     multi-vector blocked GS kernel — see docs/KERNELS.md); both honour
     weighted/biased graphs, the bias folding into each teleport row at
     submit time.  ``backend_opts`` pass through to the backend (``block``,
-    ``tile_cap``, ``interpret`` for pallas)."""
+    ``tile_cap``, ``interpret`` for pallas; without a layout the pallas
+    backend chooses one from the graph, see :attr:`layout`)."""
 
     def __init__(self, g: Graph, *, slots: int = 8, d: float = DEFAULT_DAMPING,
                  threshold: float = 1e-7, handle_dangling: bool = False,
@@ -329,6 +341,13 @@ class PPREngine:
         ``GraphDelta.touched_dst_blocks`` is quoted in."""
         return getattr(getattr(self._backend, "pg", None), "block",
                        self.backend_opts.get("block", 256))
+
+    @property
+    def layout(self) -> Optional[TileLayout]:
+        """The Pallas backend's tile layout (``None`` for the jax backend):
+        ``block``, ``tile_cap``, ``tiles``, ``fill`` and whether it was
+        chosen from the graph or passed."""
+        return getattr(self._backend, "layout", None)
 
     @property
     def slot_occupancy(self) -> float:

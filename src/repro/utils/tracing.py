@@ -14,6 +14,10 @@ counters, and the grid size of each Pallas kernel.
 * :data:`GRID_STEPS` — grid steps per call of each Pallas kernel, written by
   the kernel wrappers in :mod:`repro.kernels.spmv.kernel` when they trace,
   so a kernel's device time can be read per grid step.
+* :data:`LAYOUTS` — the tile layout each Pallas Gauss–Seidel kernel's
+  operands were last built with (``block``, ``tile_cap``, ``tiles``,
+  ``fill``, and ``chosen``: picked by
+  :func:`repro.kernels.spmv.ops.choose_layout` rather than passed).
 
 Span names and their stats (``repro.serving``):
 
@@ -33,7 +37,7 @@ import dataclasses
 
 import jax
 
-__all__ = ["GRID_STEPS", "Gauge", "ServingMetrics", "span"]
+__all__ = ["GRID_STEPS", "Gauge", "LAYOUTS", "ServingMetrics", "span"]
 
 
 def span(name: str, **args):
@@ -44,6 +48,8 @@ def span(name: str, **args):
 
 # kernel name -> grid steps per call, as last traced
 GRID_STEPS: dict[str, int] = {}
+# kernel name -> the tile layout its operands were last built with
+LAYOUTS: dict[str, dict] = {}
 
 
 @dataclasses.dataclass

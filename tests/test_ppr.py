@@ -321,6 +321,7 @@ def _oracle_band_check(g, resp, k):
 @pytest.mark.parametrize("backend,opts", [
     ("jax", {}),
     ("pallas", dict(block=64, tile_cap=256, interpret=True)),
+    ("pallas", dict(interpret=True)),  # the layout choose_layout picks
 ])
 def test_engine_mixed_batch_matches_oracle(backend, opts):
     g = rmat_graph(8, avg_degree=6, seed=7)

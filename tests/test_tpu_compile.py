@@ -13,12 +13,20 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.spmv.kernel import spmv_blocked, spmv_gs_pass, spmv_gs_pass_multi
+from repro.kernels.spmv.kernel import (
+    MAX_TILES,
+    spmv_blocked,
+    spmv_gs_pass,
+    spmv_gs_pass_multi,
+)
 
-# chip_smoke.py's layouts: full webStanford at block 1024 / cap 128, and
-# full socEpinions1 at the serving defaults (block 256 / cap 1024, 8 slots)
+# chip_smoke.py's global layout: full webStanford at block 1024 / cap 128;
+# full socEpinions1 at the registry's PPR defaults (block 256 / cap 1024,
+# 8 slots); and the layout choose_layout picks for the Graph500 scale-16
+# graph of bench/graph.py at seed 0 (block 640 / cap 128, 12,650 tiles)
 WEB = dict(n_blocks=276, block=1024, T=76_371, cap=128)
 SOC = dict(n_blocks=297, block=256, T=84_415, cap=1024, b=8)
+CHOSEN = dict(n_blocks=103, block=640, T=12_650, cap=128, b=8)
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +66,7 @@ def _args(sharding, *, n_blocks, block, T, cap, b=None):
     ("spmv_blocked", spmv_blocked, WEB),
     ("spmv_gs_pass", spmv_gs_pass, WEB),
     ("spmv_gs_pass_multi", spmv_gs_pass_multi, SOC),
+    ("spmv_gs_pass_multi", spmv_gs_pass_multi, CHOSEN),
 ])
 def test_kernel_compiles_for_v5e(one_chip, kernel, fn, widths):
     args = _args(one_chip, **widths)[kernel]
@@ -65,3 +74,17 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, fn, widths):
         lambda *a: fn(*a, block=widths["block"], interpret=False)
     ).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("extra", [0, 1])
+def test_tile_limit_is_where_smem_fills(one_chip, extra):
+    """MAX_TILES tiles compile; one more overflows the SMEM tile maps."""
+    widths = dict(CHOSEN, T=MAX_TILES + extra)
+    args = _args(one_chip, **widths)["spmv_gs_pass_multi"]
+    lower = jax.jit(lambda *a: spmv_gs_pass_multi(
+        *a, block=widths["block"], interpret=False)).lower(*args)
+    if extra:
+        with pytest.raises(Exception, match="smem"):
+            lower.compile()
+    else:
+        assert "tpu_custom_call" in lower.compile().as_text()
