@@ -56,8 +56,10 @@ pre-weighted computation — no extra multiplies.
 """
 from __future__ import annotations
 
+import concurrent.futures
 import dataclasses
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
@@ -83,6 +85,7 @@ __all__ = [
     "DeviceGraph",
     "EdgeCentricGraph",
     "PartitionedGraph",
+    "partition_edges",
     "IdenticalNodePlan",
     "pagerank_numpy",
     "l1_norm",
@@ -273,7 +276,10 @@ class PartitionedGraph:
         return self.emask if self.w_pad is None else self.w_pad
 
     @classmethod
-    def from_graph(cls, g: Graph, p: int, dtype=jnp.float32) -> "PartitionedGraph":
+    def from_graph(cls, g: Graph, p: int, dtype=jnp.float32,
+                   gain: bool = True) -> "PartitionedGraph":
+        """``gain=False`` leaves out the ``(n_pad, p)`` gain matrix, which
+        only the adaptive schedule reads."""
         vp = -(-g.n // p)
         n_pad = vp * p
         bounds = np.arange(p + 1) * vp
@@ -311,10 +317,97 @@ class PartitionedGraph:
                       else jnp.asarray(bias_pad, dtype=dtype)),
             # p is thread-scale, so the (n_pad, p) vertex-gain certificate
             # costs about one extra rank-vector per partition — cheap enough
-            # to always carry, so every partitioned bundle can run the
+            # to carry by default, so every partitioned bundle can run the
             # adaptive schedule without a rebuild
-            gain=jnp.asarray(vertex_gain_matrix(g, vp, p, n_pad), dtype=dtype),
+            gain=(jnp.asarray(vertex_gain_matrix(g, vp, p, n_pad), dtype=dtype)
+                  if gain else None),
         )
+
+
+# 16-bit digits: numpy's stable argsort of a uint16 key is a radix sort
+_DIGIT = 16
+
+
+def partition_edges(n: int, src, dst, p: int, *, workers: int | None = None):
+    """Bucket an unsorted edge list by owning partition and order each
+    partition by local destination, with no global sort and no float array.
+
+    Partition ``i`` owns the vertices ``[i·vp, (i+1)·vp)``, ``vp = ⌈n/p⌉``
+    (the :class:`PartitionedGraph` rule).  Returns ``(src_rows, local_rows,
+    counts)``: two ``(p, cap)`` int32 arrays whose row ``i`` holds partition
+    ``i``'s edges by non-decreasing local destination ``dst − i·vp`` (the
+    input order kept among equal destinations), then padding (src 0, local
+    ``vp``), and the ``(p,)`` int64 counts of real edges; ``cap`` is the
+    largest count.  Counts and offsets are int64, so the edge list may hold
+    2**31 edges or more; each row stays int32.
+
+    Two stable counting passes on 16-bit digits, on ``workers`` threads
+    (numpy's radix argsort, gathers and scatters release the GIL): edge
+    chunks by ``(partition, local >> 16)`` straight into their rows, then
+    each 65,536-vertex bucket by ``local & 0xffff``.  Host memory beyond the
+    output: one uint16 key per edge and one chunk's temporaries per thread.
+    """
+    src, dst = np.asarray(src), np.asarray(dst)
+    m, vp = int(src.shape[0]), max(1, -(-n // p))
+    per = -(-vp // (1 << _DIGIT))  # buckets per partition
+    n_buckets = p * per  # ≤ 2**16 for any int32 vertex id
+    workers = workers or min(32, os.cpu_count() or 1)
+    chunk = max(1 << 16, min(1 << 22, -(-m // (4 * workers))))
+    starts = list(range(0, m, chunk))
+
+    def local_of(d):
+        return d - (d // vp) * vp
+
+    def bucket_of(d):
+        owner = d // vp
+        return (owner * per + ((d - owner * vp) >> _DIGIT)).astype(np.uint16)
+
+    def keyed(c0):
+        k = bucket_of(dst[c0:c0 + chunk])
+        return k, np.bincount(k, minlength=n_buckets)
+
+    with concurrent.futures.ThreadPoolExecutor(workers) as pool:
+        keyed_chunks = list(pool.map(keyed, starts))
+        keys = [k for k, _ in keyed_chunks]
+        counts = np.zeros((len(starts), n_buckets), np.int64)
+        for c, (_, cnt) in enumerate(keyed_chunks):
+            counts[c] = cnt
+        del keyed_chunks
+        per_bucket = counts.sum(axis=0).reshape(p, per)
+        rows = per_bucket.sum(axis=1)
+        cap = max(1, int(rows.max(initial=0)))
+        # where each bucket starts in the flattened (p, cap) output, and
+        # where each chunk's run of it starts
+        first = (np.arange(p)[:, None] * cap + np.cumsum(per_bucket, axis=1)
+                 - per_bucket).reshape(-1)
+        chunk_first = first[None, :] + np.cumsum(counts, axis=0) - counts
+        src_rows = np.zeros((p, cap), np.int32)
+        local_rows = np.full((p, cap), vp, np.int32)
+        flat_src, flat_local = src_rows.reshape(-1), local_rows.reshape(-1)
+
+        def scatter(c):
+            c0 = starts[c]
+            order = np.argsort(keys[c], kind="stable")
+            run_first = np.cumsum(counts[c]) - counts[c]
+            pos = np.arange(order.size, dtype=np.int64)
+            pos += (chunk_first[c] - run_first)[keys[c][order]]
+            keys[c] = None
+            flat_src[pos] = src[c0:c0 + chunk][order]
+            flat_local[pos] = local_of(dst[c0:c0 + chunk][order])
+
+        list(pool.map(scatter, range(len(starts))))
+
+        def order_bucket(b):
+            lo = int(first[b])
+            hi = lo + int(per_bucket.reshape(-1)[b])
+            if hi - lo > 1:
+                low = (flat_local[lo:hi] & 0xFFFF).astype(np.uint16)
+                order = np.argsort(low, kind="stable")
+                flat_src[lo:hi] = flat_src[lo:hi][order]
+                flat_local[lo:hi] = flat_local[lo:hi][order]
+
+        list(pool.map(order_bucket, range(n_buckets)))
+    return src_rows, local_rows, rows
 
 
 # ---------------------------------------------------------------------------

@@ -3,6 +3,7 @@ from repro.graphs.csr import (
     GraphDelta,
     BlockedCOO,
     DecompositionPlan,
+    EdgeList,
     build_blocked_coo,
     blocked_tile_stats,
     patch_blocked_coo,
@@ -31,6 +32,7 @@ __all__ = [
     "GraphDelta",
     "BlockedCOO",
     "DecompositionPlan",
+    "EdgeList",
     "build_blocked_coo",
     "blocked_tile_stats",
     "patch_blocked_coo",

@@ -498,6 +498,23 @@ class Graph:
         return g_new, delta
 
 
+@dataclasses.dataclass(frozen=True)
+class EdgeList:
+    """An unsorted edge list as a loader hands it over: ``n`` vertices and
+    the directed edges ``src[k] → dst[k]`` (int32 endpoints, repeats and
+    self-loops allowed), with nothing derived from them.  The sharded build
+    (:meth:`repro.core.distributed.DistributedBundle.build`) takes it where a
+    :class:`Graph`, whose constructor sorts every edge, would not fit."""
+
+    n: int
+    src: np.ndarray
+    dst: np.ndarray
+
+    @property
+    def m(self) -> int:
+        return int(self.src.shape[0])
+
+
 def inv_out_and_dangling(out_degree: np.ndarray, n_pad: Optional[int] = None):
     """``(inv_out, dangling)`` float64 host arrays shared by every device
     bundle: 1/outdeg (0 for dangling vertices) and the outdeg==0 mask.

@@ -17,7 +17,14 @@ counters, and the grid size of each Pallas kernel.
 * :data:`LAYOUTS` — the tile layout each Pallas Gauss–Seidel kernel's
   operands were last built with (``block``, ``tile_cap``, ``tiles``,
   ``fill``, and ``chosen``: picked by
-  :func:`repro.kernels.spmv.ops.choose_layout` rather than passed).
+  :func:`repro.kernels.spmv.ops.choose_layout` rather than passed); and
+  under ``"distributed"`` the sharded layout
+  :class:`repro.core.distributed.DistributedBundle` was last built with:
+  ``p``, ``vp``, ``n_pad``, ``cap`` (edge slots per shard), ``segments``
+  (partial-sum segments per shard), ``edges_per_shard`` (real edges),
+  ``exchange_bytes_per_round`` (what each device receives in a round's
+  all-gather), and the build's host seconds ``partition_s`` and
+  ``place_s``.
 
 Span names and their stats (``repro.serving``):
 
@@ -30,6 +37,22 @@ Span names and their stats (``repro.serving``):
   and one ``ppr.harvest`` per converged slot (``qid``, ``sweeps``,
   ``converged_sweep``, ``warm``);
 * ``ppr.cache_insert``: ``n``, the answers put in the result cache.
+
+Span names and their stats (:mod:`repro.core.distributed`, the sharded
+build):
+
+* ``dist.build``: ``p``, ``vp``, ``cap``, ``edges_max``, ``edges_mean``;
+  inside it ``dist.partition`` (bucketing and ordering the edges on the
+  host, and their partial-sum segments) and ``dist.place`` (each shard's
+  rows to its device, the vectors to every device; ``bytes_per_device``).
+
+Inside each round of the distributed solvers, ``jax.named_scope``\\ s
+mark the device work in the compiled program's operation names:
+``dist.exchange`` (the rank vector's all-gather and the residual's
+all-reduce), ``dist.dangling`` (the dangling mass) and ``dist.sweep``.  A
+profiler trace's ``XLA Ops`` events are named by HLO instruction, so the
+benchmark finds the collectives by their HLO kind (``all-gather``,
+``all-reduce``) rather than by scope.
 """
 from __future__ import annotations
 
