@@ -16,10 +16,18 @@ expressed as **one-hot matmuls on the MXU**, with the one-hot laid out
 
 (``r`` = 1 for the global kernels, the batch for the PPR kernel).
 
+The one-hots are bf16 (0 and 1 are exact) and each f32 operand enters as
+three bf16 parts stacked on the row axis, so each contraction is one bf16 ×
+bf16 matmul with f32 accumulation and f32's 24-bit precision
+(:func:`_contract`).  At ``Precision.HIGHEST`` on f32 one-hots the v5e
+latched every one-hot register into the MXU six times: 1,536 latches a
+step at block 1024 / cap 128, now 128 (docs/KERNELS.md).
+
 Per tile the kernel reads ~3·cap·4 B of edge indices from HBM against
 4·cap·block MXU FLOPs per batch row.  On a v5e at block 1024 / cap 128 a
-grid step costs ~0.9 µs, far above either the HBM or the MXU time of its
-tile, so the kernel is bound by its per-step cost, not by HBM (PERF.md).
+grid step costs ~0.39 µs (~0.94 µs with the six latches), far above either
+the HBM or the MXU time of its tile, so the kernel is bound by its
+per-step cost, not by HBM (``scripts/tile_step_cost.py``).
 
 Layout rules the TPU compiler imposes (and interpret mode does not): every
 block's last two dims must be (8, 128)-aligned or span the whole array, so
@@ -47,8 +55,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.utils.platform import pallas_interpret
 from repro.utils.tracing import GRID_STEPS
 
-# one-hot entries are exact in bf16 but the ranks are not: contract in f32
-_PRECISION = jax.lax.Precision.HIGHEST
+# one-hot entries are exact in bf16 but the ranks are not: each f32 operand
+# of a tile contraction rides as this many bf16 pieces (8 bits each)
+_PARTS = 3
 # v5e's default scoped VMEM limit, and the chip's physical VMEM per core
 _DEFAULT_SCOPED_VMEM = 16 * 2**20
 VMEM_CAPACITY = 128 * 2**20
@@ -59,25 +68,59 @@ MAX_TILES = 130_048
 
 
 def _onehot(local_ids, block: int):
-    """``(1, cap)`` int32 row of block-local ids → ``(block, cap)`` f32
-    one-hot; the row broadcasts along sublanes, so no transpose is needed."""
+    """``(1, cap)`` int32 row of block-local ids → ``(block, cap)`` bf16
+    one-hot (0 and 1 are exact); the row broadcasts along sublanes, so no
+    transpose is needed."""
     ids = jax.lax.broadcasted_iota(jnp.int32, (block, local_ids.shape[-1]), 0)
-    return (ids == local_ids).astype(jnp.float32)
+    return jnp.where(ids == local_ids, 1.0, 0.0).astype(jnp.bfloat16)
+
+
+def _split(x):
+    """``(r, n)`` f32 → ``(_PARTS·r, n)`` bf16, stacked on the row axis:
+    each part is the bf16 rounding of what the parts above it left, so three
+    give ``x = hi + mid + lo`` exactly (three 8-bit significands carry f32's
+    24).  Two carry 16 bits, ~8e-6 relative: a lower precision than f32."""
+    pieces, rest = [], x
+    for _ in range(_PARTS):
+        piece = rest.astype(jnp.bfloat16)
+        pieces.append(piece)
+        rest = rest - piece.astype(jnp.float32)
+    return jnp.concatenate(pieces, axis=0)
+
+
+# (r, block) @ onehot(block, cap), and (r, cap) @ onehot(block, cap)ᵀ
+_GATHER = (((1,), (0,)), ((), ()))
+_SCATTER = (((1,), (1,)), ((), ()))
+
+
+def _contract(x, onehot, dims):
+    """``x`` (r, ·) f32 against a bf16 one-hot at f32 precision: one bf16 ×
+    bf16 MXU matmul of the stacked parts of ``x`` (:func:`_split`) with f32
+    accumulation, so each one-hot vreg is latched once (at
+    ``Precision.HIGHEST`` on f32 operands the v5e latches it six times),
+    then the parts' row groups summed in f32, largest first.  Every product
+    with a 0/1 entry is exact: a gather (one nonzero per output) returns
+    ``x``'s values bit for bit, a scatter sums them as f32 does."""
+    y = jax.lax.dot_general(_split(x), onehot, dims,
+                            preferred_element_type=jnp.float32)
+    r = x.shape[0]
+    out = y[:r]
+    for k in range(1, _PARTS):
+        out = out + y[k * r:(k + 1) * r]
+    return out
 
 
 def _tile_gather_scatter(src, dst, w, contrib):
-    """One tile's gather→scale→scatter as two one-hot MXU matmuls; every
-    kernel shares this so their tile math stays identical.
+    """One tile's gather→scale→scatter as two one-hot MXU matmuls
+    (:func:`_contract`); every kernel shares this so their tile math stays
+    identical.
 
     src/dst: (1, cap) int32 local ids; w: (1, cap) f32 validity·weight;
     contrib: (r, block) — returns the (r, block) partial accumulator."""
     block = contrib.shape[-1]
-    gathered = jnp.dot(contrib.astype(jnp.float32), _onehot(src, block),
-                       precision=_PRECISION,
-                       preferred_element_type=jnp.float32)  # (r, cap)
-    return jax.lax.dot_general(
-        gathered * w, _onehot(dst, block), (((1,), (1,)), ((), ())),
-        precision=_PRECISION, preferred_element_type=jnp.float32)  # (r, block)
+    gathered = _contract(contrib.astype(jnp.float32), _onehot(src, block),
+                         _GATHER)  # (r, cap)
+    return _contract(gathered * w, _onehot(dst, block), _SCATTER)  # (r, block)
 
 
 def _rows(x):
@@ -100,7 +143,7 @@ def _resident_spec(shape):
 def _vmem_need(resident_bytes: int, step_bytes: int) -> int:
     """The scoped VMEM a kernel asks for: the single-buffered resident
     state, two buffers of every per-step block, and room for the tile
-    body's ``(block, cap)`` one-hot temporaries."""
+    body's temporaries (:func:`_tile_body_bytes`)."""
     return resident_bytes + 2 * step_bytes + (4 << 20)
 
 
@@ -127,9 +170,14 @@ def _compiler_params(need: int):
 
 
 def _tile_body_bytes(block: int, cap: int, r: int) -> int:
-    """Per-step VMEM of the tile body: the streamed ``(1, cap)`` rows (each
-    padded to 8 sublanes) plus the two one-hot matrices and the panels."""
-    return 4 * (4 * 8 * cap + 2 * block * cap + max(r, 8) * (cap + block))
+    """Per-step VMEM of the tile body: the four streamed ``(1, cap)`` f32
+    rows (each padded to 8 sublanes), the two bf16 one-hots, each
+    contraction's ``_PARTS·r`` stacked rows in bf16 (padded to 16 sublanes)
+    and their f32 products (padded to 8), and the ``r``-row f32 panels."""
+    stacked = _PARTS * r
+    return (4 * 4 * 8 * cap + 2 * 2 * block * cap
+            + (2 * -(-stacked // 16) * 16 + 4 * -(-stacked // 8) * 8
+               + 4 * max(r, 8)) * (cap + block))
 
 
 def _spmv_kernel(sb_ref, db_ref, contrib_ref, src_ref, dst_ref, val_ref, out_ref):

@@ -66,7 +66,9 @@ SCHEDULES = ("barrier", "nosync", "adaptive")
 # STEP_US + STEP_US_PER_LANE · block · tile_cap, least squares over 12
 # lane-dense layouts (blocks 256–4096, caps 128–1024) of the Graph500
 # scale-16 graph at 8 rows, worst residual 3.2%; spmv_gs_pass at one row
-# costs 0.1-7% more (scripts/tile_step_cost.py; PERF.md, section 6)
+# costs 0.1-7% more (scripts/tile_step_cost.py; PERF.md, section 6).  Fit
+# when the tile contraction ran f32 one-hots at HIGHEST precision; the bf16
+# split's steps fit 0.220 + 1.0e-6 · block · tile_cap (docs/KERNELS.md)
 STEP_US = 0.128
 STEP_US_PER_LANE = 6.0e-6
 # the layouts choose_layout weighs: blocks of whole 128-lane rows, and caps

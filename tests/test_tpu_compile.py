@@ -6,6 +6,8 @@ The topology is described inside a module fixture, never at import: one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import base64
+import json
 import os
 
 import jax
@@ -88,3 +90,61 @@ def test_tile_limit_is_where_smem_fills(one_chip, extra):
             lower.compile()
     else:
         assert "tpu_custom_call" in lower.compile().as_text()
+
+
+def _nested_ops(op):
+    """``op`` and every op nested in its regions."""
+    yield op
+    for region in op.regions:
+        for block in region.blocks:
+            for child in block.operations:
+                yield from _nested_ops(child.operation)
+
+
+def _mosaic_ops(lowered, name):
+    """Operand types and attributes of the ops called ``name`` in the Mosaic
+    module of each TPU kernel of a lowered program, read back from the
+    custom call's serialized body."""
+    from jax._src.interpreters import mlir
+    from jaxlib.mlir import ir
+    from jaxlib.mlir.passmanager import PassManager
+
+    def attr(op, key):
+        return ir.StringAttr(op.attributes[key]).value
+
+    bodies = [json.loads(attr(op, "backend_config"))["custom_call_config"]["body"]
+              for op in _nested_ops(lowered.compiler_ir("stablehlo").operation)
+              if op.name == "stablehlo.custom_call"
+              and attr(op, "call_target_name") == "tpu_custom_call"]
+    found = []
+    with mlir.make_ir_context() as ctx:
+        ctx.allow_unregistered_dialects = True
+        for body in bodies:
+            module = ir.Module.parse(base64.b64decode(body))
+            PassManager.parse("builtin.module(mosaic-serde{serialize=false})"
+                              ).run(module.operation)
+            found += [([str(v.type) for v in op.operands],
+                       {k: str(op.attributes[k]) for k in op.attributes})
+                      for op in _nested_ops(module.operation) if op.name == name]
+    return found
+
+
+@pytest.mark.parametrize("kernel,fn,widths", [
+    ("spmv_blocked", spmv_blocked, WEB),
+    ("spmv_gs_pass", spmv_gs_pass, WEB),
+    ("spmv_gs_pass_multi", spmv_gs_pass_multi, CHOSEN),
+])
+def test_tile_contraction_latches_bf16_one_hots(one_chip, kernel, fn, widths):
+    """Each kernel's two tile contractions are bf16 × bf16 MXU matmuls with
+    f32 accumulation (the split f32 operand against the bf16 one-hot), not
+    f32 one-hots at HIGHEST precision, which the v5e latches six times."""
+    args = _args(one_chip, **widths)[kernel]
+    lowered = jax.jit(
+        lambda *a: fn(*a, block=widths["block"], interpret=False)).lower(*args)
+    matmuls = _mosaic_ops(lowered, "tpu.matmul")
+    assert len(matmuls) == 2
+    for operands, attrs in matmuls:
+        lhs, rhs, acc = operands
+        assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>"), operands
+        assert acc.endswith("xf32>"), operands
+        assert "fp32" not in attrs.get("precision", ""), attrs
