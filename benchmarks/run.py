@@ -12,8 +12,6 @@ def main() -> None:
         bench_distributed,
         bench_faults,
         bench_iterations,
-        bench_localsgd,
-        bench_roofline,
         bench_scaling,
         bench_variants,
     )
@@ -25,8 +23,6 @@ def main() -> None:
         ("Fig7 iterations", bench_iterations),
         ("Fig8/9 sleep+failure", bench_faults),
         ("stale-sync distributed", bench_distributed),
-        ("no-sync local-SGD", bench_localsgd),
-        ("roofline table", bench_roofline),
     ]
     print("name,us_per_call,derived")
     failed = 0

@@ -18,7 +18,7 @@ and interruptible at every step:
    the final store.
 
 Progress lives in ``PIPELINE.json`` (atomic rewrite after every chunk and
-stage, the ``checkpoint/ckpt.py`` idiom of a durable latest-pointer): a
+stage, a durable latest-pointer): a
 killed build resumes exactly where it stopped — completed stages are
 skipped via their store manifests, and a partially generated stage skips
 every spill chunk whose CRC still matches its record.  Chunk generation is
@@ -103,7 +103,7 @@ class BuildConfig:
 
 
 # ---------------------------------------------------------------------------
-# Progress file (the durable latest-pointer idiom of checkpoint/ckpt.py)
+# Progress file (a durable latest-pointer, rewritten atomically)
 # ---------------------------------------------------------------------------
 
 

@@ -1,9 +1,8 @@
 """Continuous-batching PPR query engine.
 
-The PPR analogue of :mod:`repro.serving.engine`'s slot-recycling idiom: a
-host-side scheduler owns a fixed ``(B, n)`` device-resident batch of rank
-rows (``B`` = ``slots``), and a jitted multi-sweep step advances every
-active slot at once:
+A host-side scheduler owns a fixed ``(B, n)`` device-resident batch of rank
+rows (``B`` = ``slots``) and recycles its slots across queries; a jitted
+multi-sweep step advances every active slot at once:
 
 * **submit** — a seed query is allocated a free slot: its teleport row is
   written into the batch's teleport matrix and its rank row is initialized

@@ -1,61 +1,16 @@
-"""Distributed behaviour: sharding rules over all archs, distributed
-PageRank (multi host-device subprocess), local-SGD, fault simulation."""
+"""Distributed behaviour: distributed PageRank (multi host-device
+subprocess) and the fault simulation."""
 import json
 import os
 import subprocess
 import sys
 import textwrap
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.configs import ARCH_IDS, get_config
 from repro.core import FaultPlan, PartitionedGraph, l1_norm, pagerank_numpy, simulate
 from repro.graphs import rmat_graph
-
-
-# ---------------------------------------------------------------------------
-# sharding rules: valid specs for every arch on the production mesh shape
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_param_specs_divide_on_production_mesh(arch):
-    from repro.launch.specs import abstract_train_state
-    from repro.sharding.rules import param_specs
-    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    cfg = get_config(arch)
-    state = abstract_train_state(cfg)
-    specs = param_specs(state.params, mesh)
-    flat_p = jax.tree_util.tree_leaves_with_path(state.params)
-    flat_s = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, jax.sharding.PartitionSpec))
-    assert len(flat_p) == len(flat_s)
-    for (path, leaf), spec in zip(flat_p, flat_s):
-        for dim, axes in zip(leaf.shape, tuple(spec) + (None,) * (leaf.ndim - len(spec))):
-            if axes is None:
-                continue
-            axes = axes if isinstance(axes, tuple) else (axes,)
-            size = int(np.prod([mesh.shape[a] for a in axes]))
-            assert dim % size == 0, f"{arch} {path} {leaf.shape} {spec}"
-
-
-def test_moe_expert_sharding_fallback():
-    """mixtral has 8 experts on a 16-way model axis → expert dim must NOT be
-    sharded; the FFN dim is sharded instead."""
-    from repro.launch.specs import abstract_params
-    from repro.sharding.rules import param_specs
-    mesh = jax.sharding.AbstractMesh((16, 16), ("data", "model"))
-    cfg = get_config("mixtral-8x22b")
-    specs = param_specs(abstract_params(cfg), mesh)
-    wi_spec = specs["layers"]["mlp"]["wi"]
-    assert wi_spec[-1] == "model" and wi_spec[-2] is None  # f sharded, E not
-
-    cfg2 = get_config("deepseek-v2-236b")
-    specs2 = param_specs(abstract_params(cfg2), mesh)
-    wi2 = specs2["layers"]["mlp"]["wi"]
-    assert wi2[-2] == "model"  # 160 experts divide 16 → EP
 
 
 # ---------------------------------------------------------------------------
@@ -207,40 +162,3 @@ def test_edge_balanced_boundaries_fix_load_skew():
 
     with pytest.raises(ValueError, match="rel_costs"):
         simulate_jittered(pg, "barrier", iterations=5, rel_costs=ev[:-1])
-
-
-# ---------------------------------------------------------------------------
-# local-SGD / no-sync DP
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_local_sgd_trains_and_syncs():
-    import dataclasses as dc
-
-    from repro.configs import get_config
-    from repro.training.local_sgd import make_local_sgd_step, replicate_state
-    from repro.training.optimizer import AdamWConfig
-    from repro.training.train_step import init_train_state
-
-    cfg = dc.replace(get_config("stablelm-3b").reduced(), dtype="float32", n_layers=1)
-    state = init_train_state(cfg, jax.random.PRNGKey(0))
-    R, H, B, S = 2, 2, 2, 16
-    ls = replicate_state(state, R)
-    step = make_local_sgd_step(cfg, AdamWConfig(lr=1e-3), inner_steps=H, compress=True, moe_dispatch="dense")
-    toks = jax.random.randint(jax.random.PRNGKey(1), (R, H, B, S), 0, cfg.vocab)
-    new, metrics = jax.jit(step)(ls, {"tokens": toks})
-    assert bool(jnp.isfinite(metrics["loss"]))
-    # after sync all replicas are identical
-    for leaf in jax.tree.leaves(new.params_r):
-        np.testing.assert_allclose(np.asarray(leaf[0]), np.asarray(leaf[1]), rtol=1e-6)
-
-
-def test_int8_quantization_roundtrip():
-    from repro.training.local_sgd import dequantize_int8, quantize_int8
-
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(1000), jnp.float32)
-    q, scale = quantize_int8(x)
-    assert q.dtype == jnp.int8
-    err = float(jnp.max(jnp.abs(dequantize_int8(q, scale) - x)))
-    assert err <= float(scale) * 0.5 + 1e-6

@@ -11,7 +11,6 @@ except ImportError:
 
 from repro.core import pagerank_numpy, l1_norm
 from repro.graphs import build_blocked_coo, rmat_graph
-from repro.kernels.flash_attention import attention_ref, flash_attention_kernel
 from repro.kernels.spmv import PallasGraph, pagerank_pallas, spmv_blocked, spmv_blocked_ref, spmv_ref
 from repro.kernels.spmv import kernel
 
@@ -121,45 +120,3 @@ def test_pallas_pagerank_end_to_end():
     pgk = PallasGraph.build(g, block=128, tile_cap=256)
     r = pagerank_pallas(pgk, threshold=1e-7, interpret=True)
     assert l1_norm(r.pr, pr_ref) < 1e-3
-
-
-# ---------------------------------------------------------------------------
-# flash attention kernel
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (4, 2), (8, 1)])
-@pytest.mark.parametrize("causal,window", [(True, None), (True, 64), (False, None)])
-def test_flash_attention_sweep(dtype, hq, hkv, causal, window, rng):
-    b, s, dh = 2, 256, 64
-    q = jnp.asarray(rng.standard_normal((b, hq, s, dh)), dtype)
-    k = jnp.asarray(rng.standard_normal((b, hkv, s, dh)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, hkv, s, dh)), dtype)
-    out = flash_attention_kernel(
-        q, k, v, scale=dh**-0.5, causal=causal, window=window,
-        block_q=64, block_k=64, interpret=True,
-    )
-    ref = attention_ref(q, k, v, scale=dh**-0.5, causal=causal, window=window)
-    tol = 2e-5 if dtype == jnp.float32 else 2e-2
-    np.testing.assert_allclose(
-        np.asarray(out, np.float32), np.asarray(ref, np.float32), atol=tol, rtol=tol
-    )
-
-
-@given(
-    st.sampled_from([64, 128, 192]),
-    st.sampled_from([32, 64]),
-    st.integers(1, 3),
-)
-@settings(max_examples=8)
-def test_property_flash_attention_shapes(s, dh, b):
-    rng = np.random.default_rng(s + dh + b)
-    q = jnp.asarray(rng.standard_normal((b, 2, s, dh)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((b, 2, s, dh)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((b, 2, s, dh)), jnp.float32)
-    out = flash_attention_kernel(
-        q, k, v, scale=dh**-0.5, causal=True, block_q=32, block_k=32, interpret=True
-    )
-    ref = attention_ref(q, k, v, scale=dh**-0.5, causal=True)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5, rtol=3e-5)
