@@ -148,7 +148,8 @@ def phase_serving(scale_down: float = 1.0, queries: int = 32) -> None:
     wall, stream_compile_s = time.perf_counter() - t0, _compile_s[0] - c0
     assert sorted(r.qid for r in responses) == list(range(queries)), "lost queries"
     iters = [r.iterations for r in responses]
-    print(f"B serve socEpinions1: n={g.n} m={g.m} tiles={pg.tiles_src_local.shape[0]} "
+    print(f"B serve socEpinions1: n={g.n} m={g.m} tiles={pg.layout.tiles} "
+          f"pad_tiles={pg.layout.pad_tiles} "
           f"layout_bytes={_nbytes(pg)} build_s={build_s:.3f} "
           f"first_query_s={first_s:.3f} (compile_s={first_compile_s:.3f}) "
           f"serve_wall_s={wall:.3f} (compile_s={stream_compile_s:.3f}) "

@@ -1,5 +1,5 @@
-"""Per-grid-step cost of the SpMV Gauss–Seidel kernels against the tile
-layout, on the chip.
+"""Per-tile and per-grid-step cost of the SpMV Gauss–Seidel kernels
+against the tile layout, on the chip.
 
     python3 scripts/tile_step_cost.py --scale 16 --seed 0 \\
         --layouts 256/1024,256/128,512/128,1024/128,1024/256
@@ -7,11 +7,13 @@ layout, on the chip.
 Draws the Graph500 Kronecker graph of ``--scale`` from ``--seed``
 (``bench/graph.py``), builds each ``block/tile_cap`` layout, and times
 back-to-back calls of ``spmv_gs_pass_multi`` at ``--rows`` rows and of
-``spmv_gs_pass`` (one row), with the layout's arrays as arguments.  A call's
-time over its grid steps is the cost of one grid step.  Prints one JSON line
-per layout and kernel, then the least-squares fit
-``us_per_step = c0 + c1 · block · cap`` over the lane-dense layouts (cap a
-multiple of 128).  Needs a TPU.
+``spmv_gs_pass`` (one row), with the layout's arrays as arguments.  A
+call's time over the layout's real tiles is the cost of a tile
+(``us_per_tile``, the pads' time included, as ``tile_step_us`` reads it);
+over its grid steps, of which each runs ``tiles_per_step`` tiles, the cost
+of a grid step (``us_per_step``).  Prints one JSON line per layout and
+kernel, then the least-squares fit ``us_per_tile = c0 + c1 · block · cap``
+over the lane-dense layouts (cap a multiple of 128).  Needs a TPU.
 """
 from __future__ import annotations
 
@@ -32,7 +34,7 @@ import numpy as np  # noqa: E402
 from bench import graph  # noqa: E402
 from repro.graphs.csr import Graph  # noqa: E402
 from repro.kernels.spmv.kernel import (  # noqa: E402
-    MAX_TILES,
+    multi_pass_tiles_per_step,
     spmv_gs_pass,
     spmv_gs_pass_multi,
 )
@@ -59,17 +61,24 @@ def _seconds_per_call(call, x) -> float:
 
 def measure(g: Graph, block: int, cap: int, rows: int) -> list[dict]:
     t = time.perf_counter()
-    pg = PallasGraph.build(g, block=block, tile_cap=cap)
+    try:
+        pg = PallasGraph.build(g, block=block, tile_cap=cap)
+    except ValueError as e:  # the tile maps would overflow SMEM
+        return [dict(block=block, tile_cap=cap, skipped=str(e))]
     build_s = time.perf_counter() - t
     tiles = (pg.tiles_src_local, pg.tiles_dst_local, pg.tiles_valid,
              pg.tiles_valid, pg.tile_src_block, pg.tile_dst_block)
-    n_tiles = int(pg.tiles_src_local.shape[0])
-    if n_tiles > MAX_TILES:  # the tile maps would overflow SMEM
-        return [dict(block=block, tile_cap=cap, tiles=n_tiles, skipped=True)]
+    lay = pg.layout
     nb = pg.n_blocks
     vmask = (jnp.arange(nb * block) < g.n).astype(jnp.float32).reshape(nb, block)
-    common = dict(block=block, tile_cap=cap, tiles=n_tiles,
-                  fill=g.m / (n_tiles * cap), build_s=build_s)
+    common = dict(block=block, tile_cap=cap, tiles=lay.tiles,
+                  pad_tiles=lay.pad_tiles, fill=lay.fill, build_s=build_s)
+    padded = lay.tiles + lay.pad_tiles
+
+    def rates(seconds, per_step):
+        return dict(sweep_ms=1e3 * seconds, tiles_per_step=per_step,
+                    us_per_tile=1e6 * seconds / lay.tiles,
+                    us_per_step=1e6 * seconds * per_step / padded)
 
     multi = jnp.full((nb, rows, block), 1.0 / g.n, jnp.float32)
     base = jnp.full((nb, rows, block), 0.15 / g.n, jnp.float32)
@@ -86,15 +95,16 @@ def measure(g: Graph, block: int, cap: int, rows: int) -> list[dict]:
         lambda pr: spmv_gs_pass(pr, pg.inv_out_blocks, vmask, vmask, zeros,
                                 params, *tiles, block=block), single)
     return [dict(common, kernel="spmv_gs_pass_multi", rows=rows,
-                 sweep_ms=1e3 * s_multi, us_per_step=1e6 * s_multi / n_tiles),
+                 **rates(s_multi, multi_pass_tiles_per_step(nb, block, cap,
+                                                            rows))),
             dict(common, kernel="spmv_gs_pass", rows=1,
-                 sweep_ms=1e3 * s_single, us_per_step=1e6 * s_single / n_tiles)]
+                 **rates(s_single, lay.tiles_per_step))]
 
 
 def fit(points: list[dict]) -> dict:
-    """Least squares of ``us_per_step`` on ``[1, block·cap]``."""
+    """Least squares of ``us_per_tile`` on ``[1, block·cap]``."""
     area = np.asarray([p["block"] * p["tile_cap"] for p in points], float)
-    us = np.asarray([p["us_per_step"] for p in points])
+    us = np.asarray([p["us_per_tile"] for p in points])
     (c0, c1), *_ = np.linalg.lstsq(np.stack([np.ones_like(area), area], 1),
                                    us, rcond=None)
     pred = c0 + c1 * area

@@ -12,7 +12,7 @@ the program instead:
    instead of executing.  Nothing runs; the captured ``grid``, ``in_specs``,
    ``out_specs`` and ``scratch_shapes`` ARE the kernel's memory contract.
 2. **Symbolize** — every dimension is attributed to one of the symbols
-   ``(n_blocks, block, b, cap, T)`` by its sentinel value, so footprints
+   ``(n_blocks, block, b, cap, T, groups)`` by its sentinel value, so footprints
    come out as closed forms, not numbers for one shape.
 3. **Classify residency** — an operand whose index map is constant across
    the whole grid (for any prefetch content) is VMEM-resident for the whole
@@ -47,9 +47,11 @@ from repro.analysis.findings import Finding
 VMEM_BYTES = 16 * 2**20
 
 # Sentinel primes: each symbol gets a distinct value no other dimension can
-# collide with (the real kernels also use dims 1 and 3, which stay literal).
+# collide with (the real kernels also use dims 1, 3 and 8, which stay
+# literal).  ``T`` is the prime 13 times the 8 tiles of a Gauss–Seidel
+# grid step, so ``groups`` (T / 8, one grid step each) is 13.
 SYMBOLS: dict[str, int] = {
-    "n_blocks": 5, "block": 7, "cap": 11, "T": 13, "b": 17,
+    "n_blocks": 5, "block": 7, "cap": 11, "T": 104, "groups": 13, "b": 17,
 }
 _VALUE_TO_SYMBOL = {v: k for k, v in SYMBOLS.items()}
 

@@ -25,22 +25,28 @@ step at block 1024 / cap 128, now 128 (docs/KERNELS.md).
 
 Per tile the kernel reads ~3·cap·4 B of edge indices from HBM against
 4·cap·block MXU FLOPs per batch row.  On a v5e at block 1024 / cap 128 a
-grid step costs ~0.39 µs (~0.94 µs with the six latches), far above either
-the HBM or the MXU time of its tile, so the kernel is bound by its
-per-step cost, not by HBM (``scripts/tile_step_cost.py``).
+one-tile grid step cost ~0.39 µs (~0.94 µs with the six latches), far
+above either the HBM or the MXU time of its tile: the kernels are bound by
+their per-step cost, not by HBM (``scripts/tile_step_cost.py``).
 
 Layout rules the TPU compiler imposes (and interpret mode does not): every
 block's last two dims must be (8, 128)-aligned or span the whole array, so
-per-tile and per-block rows are streamed from ``(T, 1, cap)`` /
-``(n_blocks, 1, block)`` views with the leading dim squeezed (``None``), and
-whole-pass state is one full ``(n_blocks, block)`` block addressed by row
-(``ref[pl.ds(i, 1), :]``).  Scalars ride in SMEM.
+tile rows are streamed from ``(T / group, group, cap)`` views and per-block
+rows from ``(n_blocks, 1, block)`` views, the leading dim squeezed
+(``None``), and whole-pass state is one full ``(n_blocks, block)`` block
+addressed by row (``ref[pl.ds(i, 1), :]``).  Scalars ride in SMEM.
 
-Grid: one step per tile, tiles sorted by dst_block → each output block is
-resident in VMEM for one contiguous run of grid steps (standard Pallas
-reduction/revisiting pattern, initialized via ``pl.when`` on run start).
-The Gauss–Seidel wrappers record their grid size in
-:data:`repro.utils.tracing.GRID_STEPS` when they trace.
+Grid: tiles sorted by dst_block → each output block is resident in VMEM
+for one contiguous run of grid steps (standard Pallas reduction/revisiting
+pattern, initialized via ``pl.when`` on run start).  The Jacobi kernel runs
+one tile a step.  The Gauss–Seidel kernels run a group of
+:data:`GROUP` tiles a step (fewer only where VMEM requires it,
+:func:`_fit_group`): the group's ``(GROUP, cap)`` int32/f32 rows fill the
+8 sublanes one tile's row occupies alone, and its tiles' contractions are
+independent, so the step's fixed cost is paid once per group and their
+MXU and VPU latencies overlap.  ``PallasGraph.build`` pads each dst-block
+run to whole groups with tiles of zero validity, so no group spans two dst
+blocks and no commit falls inside a group.
 Scalar-prefetched tile→block maps drive the BlockSpec index maps.
 """
 from __future__ import annotations
@@ -53,7 +59,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.utils.platform import pallas_interpret
-from repro.utils.tracing import GRID_STEPS
 
 # one-hot entries are exact in bf16 but the ranks are not: each f32 operand
 # of a tile contraction rides as this many bf16 pieces (8 bits each)
@@ -65,6 +70,9 @@ VMEM_CAPACITY = 128 * 2**20
 # scalar-prefetched into 1 MiB of SMEM.  The v5e compiler accepts 130,048
 # tiles and refuses 130,049 (tests/test_tpu_compile.py)
 MAX_TILES = 130_048
+# tiles a Gauss–Seidel grid step runs: the (1, cap) int32/f32 row of one
+# tile already occupies a whole (8, 128) vreg tile, which eight rows fill
+GROUP = 8
 
 
 def _onehot(local_ids, block: int):
@@ -123,13 +131,23 @@ def _tile_gather_scatter(src, dst, w, contrib):
     return _contract(gathered * w, _onehot(dst, block), _SCATTER)  # (r, block)
 
 
-def _rows(x):
-    """``(T, cap)`` → ``(T, 1, cap)``: one aligned-or-full block per row."""
-    return x.reshape(x.shape[0], 1, x.shape[1])
+def _groups(x, group: int):
+    """``(T, cap)`` → ``(T / group, group, cap)``: one aligned-or-full block
+    per group of tiles."""
+    return x.reshape(x.shape[0] // group, group, x.shape[1])
 
 
-def _tile_spec(cap: int):
-    return pl.BlockSpec((None, 1, cap), lambda t, sb, db: (t, 0, 0))
+def _grid(tiles: int, group: int) -> tuple[int]:
+    """One grid step per group; the tiles must come in whole groups."""
+    if tiles % group:
+        raise ValueError(
+            f"{tiles} tiles are not whole groups of {group}: build the "
+            f"layout with PallasGraph.build, which pads each dst-block run")
+    return (tiles // group,)
+
+
+def _tile_spec(group: int, cap: int):
+    return pl.BlockSpec((None, group, cap), lambda t, sb, db: (t, 0, 0))
 
 
 def _resident_spec(shape):
@@ -142,18 +160,59 @@ def _resident_spec(shape):
 
 def _vmem_need(resident_bytes: int, step_bytes: int) -> int:
     """The scoped VMEM a kernel asks for: the single-buffered resident
-    state, two buffers of every per-step block, and room for the tile
+    state, two buffers of every per-step block, and room for the step
     body's temporaries (:func:`_tile_body_bytes`)."""
     return resident_bytes + 2 * step_bytes + (4 << 20)
 
 
-def multi_pass_vmem_bytes(n_blocks: int, block: int, cap: int, b: int) -> int:
-    """The scoped VMEM :func:`spmv_gs_pass_multi` asks for at a layout: the
-    rank, base and state panels (``b`` padded to 8 sublanes) and the two
-    per-vertex rows, each single-buffered, plus the tile body."""
+def _fit_group(resident_bytes: int, block: int, cap: int, r: int
+               ) -> tuple[int, int]:
+    """``(tiles a step, scoped VMEM)`` of a Gauss–Seidel kernel: the most
+    tiles, :data:`GROUP` halved until the bodies fit a core's VMEM beside
+    the resident state, and the VMEM that asks for.  One tile a step needs
+    what the kernels asked for before they grouped tiles, so grouping never
+    refuses a layout that one tile a step would take."""
+    group = GROUP
+    need = _vmem_need(resident_bytes, _tile_body_bytes(block, cap, r, group))
+    while group > 1 and need > VMEM_CAPACITY:
+        group //= 2
+        need = _vmem_need(resident_bytes,
+                          _tile_body_bytes(block, cap, r, group))
+    return group, need
+
+
+def _pass_resident_bytes(n_blocks: int, block: int) -> int:
+    """:func:`spmv_gs_pass`'s five resident inputs and the state, each
+    single-buffered."""
+    return 6 * n_blocks * block * 4
+
+
+def _multi_resident_bytes(n_blocks: int, block: int, b: int) -> int:
+    """:func:`spmv_gs_pass_multi`'s rank, base and state panels (``b``
+    padded to 8 sublanes) and its two per-vertex rows, each
+    single-buffered."""
     panel_bytes = n_blocks * -(-b // 8) * 8 * block * 4
-    resident = 3 * panel_bytes + 2 * n_blocks * block * 4
-    return _vmem_need(resident, _tile_body_bytes(block, cap, b))
+    return 3 * panel_bytes + 2 * n_blocks * block * 4
+
+
+def pass_tiles_per_step(n_blocks: int, block: int, cap: int) -> int:
+    """Tiles a grid step of :func:`spmv_gs_pass` runs at a layout."""
+    return _fit_group(_pass_resident_bytes(n_blocks, block), block, cap, 1)[0]
+
+
+def multi_pass_tiles_per_step(n_blocks: int, block: int, cap: int,
+                              b: int) -> int:
+    """Tiles a grid step of :func:`spmv_gs_pass_multi` runs at a layout."""
+    return _fit_group(_multi_resident_bytes(n_blocks, block, b),
+                      block, cap, b)[0]
+
+
+def multi_pass_vmem_bytes(n_blocks: int, block: int, cap: int, b: int) -> int:
+    """The scoped VMEM :func:`spmv_gs_pass_multi` asks for at a layout: its
+    resident state (:func:`_multi_resident_bytes`) plus the body of the
+    tiles a step runs (:func:`_fit_group`)."""
+    return _fit_group(_multi_resident_bytes(n_blocks, block, b),
+                      block, cap, b)[1]
 
 
 def _compiler_params(need: int):
@@ -169,15 +228,18 @@ def _compiler_params(need: int):
         vmem_limit_bytes=max(_DEFAULT_SCOPED_VMEM, need))
 
 
-def _tile_body_bytes(block: int, cap: int, r: int) -> int:
-    """Per-step VMEM of the tile body: the four streamed ``(1, cap)`` f32
-    rows (each padded to 8 sublanes), the two bf16 one-hots, each
-    contraction's ``_PARTS·r`` stacked rows in bf16 (padded to 16 sublanes)
-    and their f32 products (padded to 8), and the ``r``-row f32 panels."""
+def _tile_body_bytes(block: int, cap: int, r: int, group: int = 1) -> int:
+    """Per-step VMEM of the body of ``group`` tiles: the four streamed
+    ``(group, cap)`` 4-byte blocks (padded to 8 sublanes), and for every
+    tile of the group, as its unrolled bodies may all be live at once, the
+    two bf16 one-hots, each contraction's ``_PARTS·r`` stacked rows in bf16
+    (padded to 16 sublanes) and their f32 products (padded to 8), and the
+    ``r``-row f32 panels."""
     stacked = _PARTS * r
-    return (4 * 4 * 8 * cap + 2 * 2 * block * cap
-            + (2 * -(-stacked // 16) * 16 + 4 * -(-stacked // 8) * 8
-               + 4 * max(r, 8)) * (cap + block))
+    per_tile = (2 * 2 * block * cap
+                + (2 * -(-stacked // 16) * 16 + 4 * -(-stacked // 8) * 8
+                   + 4 * max(r, 8)) * (cap + block))
+    return 4 * 4 * -(-group // 8) * 8 * cap + group * per_tile
 
 
 def _spmv_kernel(sb_ref, db_ref, contrib_ref, src_ref, dst_ref, val_ref, out_ref):
@@ -214,7 +276,7 @@ def spmv_blocked(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(T,),
-        in_specs=[row, _tile_spec(cap), _tile_spec(cap), _tile_spec(cap)],
+        in_specs=[row] + [_tile_spec(1, cap)] * 3,
         out_specs=pl.BlockSpec((None, 1, block), lambda t, sb, db: (db[t], 0, 0)),
     )
     out = pl.pallas_call(
@@ -225,7 +287,7 @@ def spmv_blocked(
             0, 2 * 4 * 8 * block + _tile_body_bytes(block, cap, 1))),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, contrib_blocks.reshape(n_blocks, 1, block),
-      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid))
+      *(_groups(x, 1) for x in (tiles_src_local, tiles_dst_local, tiles_valid)))
     return out.reshape(n_blocks, block)
 
 
@@ -256,17 +318,40 @@ def spmv_blocked(
 # schedule shards first, see core/distributed.py).
 
 
+def _group_step(db_ref, group: int):
+    """This grid step's dst block, and whether the step starts or ends that
+    block's run.  A group never spans two dst blocks, so its first tile's
+    dst block is the group's."""
+    t = pl.program_id(0)
+    last = pl.num_programs(0) - 1
+    db = db_ref[t * group]
+    is_run_start = (t == 0) | (db_ref[jnp.maximum(t - 1, 0) * group] != db)
+    is_run_end = (t == last) | (db_ref[jnp.minimum(t + 1, last) * group] != db)
+    return t, db, is_run_start, is_run_end
+
+
+def _accumulate_group(acc_ref, sb_ref, src_ref, dst_ref, val_ref, wt_ref,
+                      contrib_of):
+    """Adds the step's tiles' partials into the run's accumulator in tile
+    order, one at a time, so the sums round exactly as with one tile a
+    step; pad tiles add exact zeros.  ``contrib_of(sb)`` reads src block
+    ``sb``'s contributions from the resident state: no commit falls inside
+    a group, so the group's tiles are independent of one another."""
+    group = src_ref.shape[0]
+    first = pl.program_id(0) * group
+    acc = acc_ref[...]
+    for g in range(group):
+        tile = pl.ds(g, 1)
+        acc = acc + _tile_gather_scatter(
+            src_ref[tile, :], dst_ref[tile, :],
+            val_ref[tile, :] * wt_ref[tile, :], contrib_of(sb_ref[first + g]))
+    acc_ref[...] = acc
+
+
 def _spmv_gs_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref, vmask_ref,
                     bias_ref, frozen_ref, src_ref, dst_ref, val_ref, wt_ref,
                     pr_ref, acc_ref):
-    t = pl.program_id(0)
-    num_t = pl.num_programs(0)
-    db = db_ref[t]
-    sb = sb_ref[t]
-    prev = jnp.maximum(t - 1, 0)
-    nxt = jnp.minimum(t + 1, num_t - 1)
-    is_run_start = (t == 0) | (db_ref[prev] != db)
-    is_run_end = (t == num_t - 1) | (db_ref[nxt] != db)
+    t, db, is_run_start, is_run_end = _group_step(db_ref, src_ref.shape[0])
 
     @pl.when(t == 0)
     def _load_state():
@@ -280,10 +365,12 @@ def _spmv_gs_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref, vmask_ref,
     # The per-edge weights operand scales each lane of the one-hot contraction
     # (val·wt folds validity and weight; the unweighted caller passes the
     # {0,1} validity mask for wt, making the product a no-op).
-    src_rows = pl.ds(sb, 1)
-    contrib = pr_ref[src_rows, :] * inv_ref[src_rows, :]
-    acc_ref[...] += _tile_gather_scatter(src_ref[...], dst_ref[...],
-                                         val_ref[...] * wt_ref[...], contrib)
+    def contrib_of(sb):
+        src_rows = pl.ds(sb, 1)
+        return pr_ref[src_rows, :] * inv_ref[src_rows, :]
+
+    _accumulate_group(acc_ref, sb_ref, src_ref, dst_ref, val_ref, wt_ref,
+                      contrib_of)
 
     @pl.when(is_run_end)
     def _commit_block():
@@ -331,9 +418,12 @@ def spmv_gs_pass(
     VMEM-resident ``(n_blocks, block)`` operand, same footprint as
     ``vmask_blocks``).
 
-    ``tiles_weight`` is the per-edge weights operand (tile layout, one
-    ``(1, cap)`` row streamed per grid step alongside the index tiles); it
-    scales each edge's gathered contribution inside the one-hot tile matmul.
+    The tile arrays hold whole groups of :data:`GROUP` tiles, none spanning
+    two dst blocks (``PallasGraph.build`` pads each dst-block run).
+
+    ``tiles_weight`` is the per-edge weights operand (tile layout, streamed
+    alongside the index tiles); it scales each edge's gathered contribution
+    inside the one-hot tile matmul.
     ``bias_blocks`` is the per-vertex teleport-bias operand multiplying the
     ``base`` scalar at commit; ``params`` carries ``[base, d, dmass]`` with
     the dangling mass kept separate because redistribution is uniform, never
@@ -342,30 +432,28 @@ def spmv_gs_pass(
     traffic, and ``val·val = val`` for a {0,1} mask)."""
     n_blocks = pr_blocks.shape[0]
     T, cap = tiles_src_local.shape
-    GRID_STEPS["spmv_gs_pass"] = T
+    group, need = _fit_group(_pass_resident_bytes(n_blocks, block),
+                             block, cap, 1)
     state = _resident_spec((n_blocks, block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(T,),
+        grid=_grid(T, group),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [state] * 5 + [_tile_spec(cap)] * 4,
+        + [state] * 5 + [_tile_spec(group, cap)] * 4,
         out_specs=state,
         scratch_shapes=[pltpu.VMEM((1, block), jnp.float32)],
     )
-    # five resident inputs + the state, each single-buffered
-    resident = 6 * n_blocks * block * 4
     return pl.pallas_call(
         _spmv_gs_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, block), pr_blocks.dtype),
-        compiler_params=_compiler_params(
-            _vmem_need(resident, _tile_body_bytes(block, cap, 1))),
+        compiler_params=_compiler_params(need),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
       inv_out_blocks, vmask_blocks, bias_blocks, frozen_blocks,
-      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid),
-      _rows(tiles_weight))
+      *(_groups(x, group) for x in (tiles_src_local, tiles_dst_local,
+                                    tiles_valid, tiles_weight)))
 
 
 # ---------------------------------------------------------------------------
@@ -399,14 +487,7 @@ def spmv_gs_pass(
 def _spmv_gs_multi_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref,
                           vmask_ref, frozen_ref, base_ref, src_ref, dst_ref,
                           val_ref, wt_ref, pr_ref, acc_ref):
-    t = pl.program_id(0)
-    num_t = pl.num_programs(0)
-    db = db_ref[t]
-    sb = sb_ref[t]
-    prev = jnp.maximum(t - 1, 0)
-    nxt = jnp.minimum(t + 1, num_t - 1)
-    is_run_start = (t == 0) | (db_ref[prev] != db)
-    is_run_end = (t == num_t - 1) | (db_ref[nxt] != db)
+    t, db, is_run_start, is_run_end = _group_step(db_ref, src_ref.shape[0])
 
     @pl.when(t == 0)
     def _load_state():
@@ -419,9 +500,11 @@ def _spmv_gs_multi_kernel(sb_ref, db_ref, params_ref, pr0_ref, inv_ref,
     # Fresh gather of the whole batch panel: (b, block) ranks of src block sb.
     # validity·weight folds the per-edge weights operand into the panel
     # (unweighted callers pass tiles_valid as wt: val² = val for a {0,1} mask)
-    contrib = pr_ref[sb] * inv_ref[pl.ds(sb, 1), :]  # (b, block)
-    acc_ref[...] += _tile_gather_scatter(src_ref[...], dst_ref[...],
-                                         val_ref[...] * wt_ref[...], contrib)
+    def contrib_of(sb):
+        return pr_ref[sb] * inv_ref[pl.ds(sb, 1), :]  # (b, block)
+
+    _accumulate_group(acc_ref, sb_ref, src_ref, dst_ref, val_ref, wt_ref,
+                      contrib_of)
 
     @pl.when(is_run_end)
     def _commit_block():
@@ -458,23 +541,25 @@ def spmv_gs_pass_multi(
     uniform (per-vertex bias also folds in here: the caller scales the
     teleport rows, so this kernel needs no separate bias operand).
     ``tiles_weight`` is the per-edge weights operand shared across the
-    whole batch — one ``(1, cap)`` stream per tile scales the ``(b, cap)``
-    gathered panel; unweighted callers pass ``tiles_valid``.  ``frozen_rows``
+    whole batch — one ``cap``-lane row per tile scales the ``(b, cap)``
+    gathered panel; unweighted callers pass ``tiles_valid``.  The tile
+    arrays hold whole groups, as :func:`spmv_gs_pass` takes them.  ``frozen_rows``
     freezes whole rows (serving slots), not single vertices; with ``b=1``,
     all-zeros mask and a uniform base this pass is exactly
     :func:`spmv_gs_pass` on one vector."""
     n_blocks, b, _ = pr_blocks.shape
     T, cap = tiles_src_local.shape
-    GRID_STEPS["spmv_gs_pass_multi"] = T
+    group, need = _fit_group(_multi_resident_bytes(n_blocks, block, b),
+                             block, cap, b)
     vertex = _resident_spec((n_blocks, block))
     panel = _resident_spec((n_blocks, b, block))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(T,),
+        grid=_grid(T, group),
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), panel, vertex,
                   vertex, _resident_spec((b, 1)), panel]
-        + [_tile_spec(cap)] * 4,
+        + [_tile_spec(group, cap)] * 4,
         out_specs=panel,
         scratch_shapes=[pltpu.VMEM((b, block), jnp.float32)],
     )
@@ -482,10 +567,9 @@ def spmv_gs_pass_multi(
         _spmv_gs_multi_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_blocks, b, block), pr_blocks.dtype),
-        compiler_params=_compiler_params(
-            multi_pass_vmem_bytes(n_blocks, block, cap, b)),
+        compiler_params=_compiler_params(need),
         interpret=pallas_interpret(interpret),
     )(tile_src_block, tile_dst_block, params.reshape(-1), pr_blocks,
       inv_out_blocks, vmask_blocks, frozen_rows.reshape(b, 1), base_blocks,
-      _rows(tiles_src_local), _rows(tiles_dst_local), _rows(tiles_valid),
-      _rows(tiles_weight))
+      *(_groups(x, group) for x in (tiles_src_local, tiles_dst_local,
+                                    tiles_valid, tiles_weight)))

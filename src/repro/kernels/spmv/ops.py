@@ -28,6 +28,7 @@ registration pays for it).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import NamedTuple
 
@@ -45,6 +46,7 @@ from repro.core.solver import (
     solve,
 )
 from repro.graphs.csr import (
+    BlockedCOO,
     Graph,
     block_pair_counts,
     build_blocked_coo,
@@ -52,13 +54,15 @@ from repro.graphs.csr import (
     tiles_from_counts,
 )
 from repro.kernels.spmv.kernel import (
+    GROUP,
     MAX_TILES,
     VMEM_CAPACITY,
     multi_pass_vmem_bytes,
+    pass_tiles_per_step,
     spmv_blocked,
     spmv_gs_pass,
 )
-from repro.utils.tracing import LAYOUTS
+from repro.utils.tracing import GRID_STEPS, LAYOUTS
 
 SCHEDULES = ("barrier", "nosync", "adaptive")
 
@@ -68,7 +72,9 @@ SCHEDULES = ("barrier", "nosync", "adaptive")
 # scale-16 graph at 8 rows, worst residual 3.2%; spmv_gs_pass at one row
 # costs 0.1-7% more (scripts/tile_step_cost.py; PERF.md, section 6).  Fit
 # when the tile contraction ran f32 one-hots at HIGHEST precision; the bf16
-# split's steps fit 0.220 + 1.0e-6 · block · tile_cap (docs/KERNELS.md)
+# split's steps fit 0.220 + 1.0e-6 · block · tile_cap (docs/KERNELS.md),
+# and a grid step now runs GROUP tiles.  Left as they are: a refit moves
+# choose_layout's pick on the served graph (PERF.md, section 7)
 STEP_US = 0.128
 STEP_US_PER_LANE = 6.0e-6
 # the layouts choose_layout weighs: blocks of whole 128-lane rows, and caps
@@ -126,9 +132,52 @@ class TileLayout(NamedTuple):
 
     block: int
     tile_cap: int
-    tiles: int
+    tiles: int  # real tiles, as build_blocked_coo makes them
     fill: float  # edges / (tiles · tile_cap): one-hot lanes that hold an edge
     chosen: bool = False  # picked by choose_layout, not passed
+    pad_tiles: int = 0  # tiles that pad dst-block runs to whole groups
+    # tiles a grid step of the Gauss–Seidel kernel runs: spmv_gs_pass's as
+    # built, spmv_gs_pass_multi's once the PPR engine records it
+    tiles_per_step: int = 1
+
+
+def pad_runs_to_groups(b: BlockedCOO, group: int = GROUP) -> BlockedCOO:
+    """``b`` with each dst-block run of tiles padded to a multiple of
+    ``group``, so that no grid step of the Gauss–Seidel kernels spans two
+    dst blocks.  A pad tile has zero validity and weight, its run's dst
+    block, and the src block of the run's last real tile (a panel the step
+    already reads); the real tiles keep their order."""
+    counts = np.bincount(b.tile_dst_block, minlength=b.n_blocks)
+    if not counts.size:
+        return b
+    padded = -(-counts // group) * group
+    run_start = np.cumsum(counts) - counts
+    db = b.tile_dst_block
+    # where each real tile lands: its run's padded start plus its offset
+    pos = (np.cumsum(padded) - padded)[db] + np.arange(db.size) - run_start[db]
+
+    def spread(x, fill=None):
+        """``x``'s rows in their padded places; a pad is zero, or the row
+        of ``fill`` that belongs to its run."""
+        if fill is None:
+            out = np.zeros((int(padded.sum()),) + x.shape[1:], x.dtype)
+        else:
+            out = np.repeat(fill, padded, axis=0)
+        out[pos] = x
+        return out
+
+    # build_blocked_coo gives every dst block a tile, so each run has a last
+    last = run_start + counts - 1
+    return dataclasses.replace(
+        b,
+        tiles_src_local=spread(b.tiles_src_local),
+        tiles_dst_local=spread(b.tiles_dst_local),
+        tiles_valid=spread(b.tiles_valid),
+        tile_src_block=spread(b.tile_src_block, b.tile_src_block[last]),
+        tile_dst_block=spread(db, np.arange(b.n_blocks, dtype=db.dtype)),
+        tiles_weight=(None if b.tiles_weight is None
+                      else spread(b.tiles_weight)),
+    )
 
 
 class PallasGraph(NamedTuple):
@@ -156,7 +205,17 @@ class PallasGraph(NamedTuple):
     @classmethod
     def build(cls, g: Graph, block: int = 256, tile_cap: int = 1024,
               gain: bool = False) -> "PallasGraph":
-        b = build_blocked_coo(g, block=block, tile_cap=tile_cap)
+        """The layout of ``g`` at ``block``/``tile_cap``: the tiles of
+        :func:`~repro.graphs.csr.build_blocked_coo`, each dst-block run
+        padded to whole groups (:func:`pad_runs_to_groups`)."""
+        real = build_blocked_coo(g, block=block, tile_cap=tile_cap)
+        b = pad_runs_to_groups(real)
+        if b.num_tiles > MAX_TILES:
+            raise ValueError(
+                f"{g.n:,} vertices at block {block} / tile_cap {tile_cap} "
+                f"make {b.num_tiles:,} tiles padded to whole groups, more "
+                f"than one kernel call takes ({MAX_TILES:,}); choose a "
+                f"layout with fewer (choose_layout)")
         n_pad = b.n_blocks * block
         inv, dang = inv_out_and_dangling(g.out_degree, n_pad)
         inv = inv.astype(np.float32)
@@ -189,8 +248,12 @@ class PallasGraph(NamedTuple):
                           else jnp.asarray(b.tiles_weight)),
             bias_blocks=bias_blocks,
             gain=gain_mat,
-            layout=TileLayout(block, tile_cap, b.num_tiles,
-                              g.m / max(1, b.num_tiles * tile_cap)),
+            layout=TileLayout(
+                block, tile_cap, real.num_tiles,
+                g.m / max(1, real.num_tiles * tile_cap),
+                pad_tiles=b.num_tiles - real.num_tiles,
+                tiles_per_step=pass_tiles_per_step(b.n_blocks, block,
+                                                   tile_cap)),
         )
 
 
@@ -311,6 +374,7 @@ def pagerank_pallas(
                               jnp.asarray(0.0, jnp.float32))
     if schedule != "barrier" and pg.layout is not None:
         LAYOUTS["spmv_gs_pass"] = pg.layout._asdict()
+        GRID_STEPS["spmv_gs_pass"] = pg.layout.tiles
     warm = None
     if pr0 is not None:
         padded = np.zeros(pg.n_blocks * pg.block, dtype=np.float32)

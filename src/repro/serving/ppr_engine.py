@@ -43,6 +43,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from repro.core.pagerank import DeviceGraph
 from repro.core.solver import DEFAULT_DAMPING
 from repro.graphs.csr import Graph
+from repro.kernels.spmv.kernel import multi_pass_tiles_per_step
 from repro.kernels.spmv.ops import PallasGraph, TileLayout, choose_layout
 from repro.ppr.batched import (
     bias_scaled,
@@ -52,7 +53,7 @@ from repro.ppr.batched import (
     teleport_from_seeds,
 )
 from repro.ppr.push import topk
-from repro.utils.tracing import LAYOUTS, span
+from repro.utils.tracing import GRID_STEPS, LAYOUTS, span
 
 __all__ = ["PPRQuery", "PPRResponse", "PPREngine", "make_query_stream",
            "shard_batch_step"]
@@ -196,8 +197,11 @@ class _PallasBackend(_Backend):
                                tile_cap=1024 if tile_cap is None else tile_cap)
         self.n = g.n
         self.pg = pg
-        self.layout = pg.layout._replace(chosen=chosen)
+        self.layout = pg.layout._replace(
+            chosen=chosen, tiles_per_step=multi_pass_tiles_per_step(
+                pg.n_blocks, pg.block, pg.layout.tile_cap, slots))
         LAYOUTS["spmv_gs_pass_multi"] = self.layout._asdict()
+        GRID_STEPS["spmv_gs_pass_multi"] = self.layout.tiles
         self.state = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         self.tele = jnp.zeros((pg.n_blocks, slots, pg.block), jnp.float32)
         sweep = make_batched_pallas_sweep(

@@ -11,13 +11,18 @@ counters, and the grid size of each Pallas kernel.
   admitted, completed, rejected, expired, cache hits/misses/evictions/
   invalidations, update batches) and its ``queue_depth`` gauge, as plain
   ints and running mean/max, so a long run stays O(1) in memory.
-* :data:`GRID_STEPS` — grid steps per call of each Pallas kernel, written by
-  the kernel wrappers in :mod:`repro.kernels.spmv.kernel` when they trace,
-  so a kernel's device time can be read per grid step.
+* :data:`GRID_STEPS` — the real tiles one call of each Pallas Gauss–Seidel
+  kernel processes (pad tiles not counted), written beside
+  :data:`LAYOUTS` by whoever hands the kernel its layout
+  (:func:`repro.kernels.spmv.ops.pagerank_pallas`, the PPR engine), so a
+  kernel's device time can be read per real tile, the pads' cost
+  included.  A grid step runs ``tiles_per_step`` of them.
 * :data:`LAYOUTS` — the tile layout each Pallas Gauss–Seidel kernel's
-  operands were last built with (``block``, ``tile_cap``, ``tiles``,
-  ``fill``, and ``chosen``: picked by
-  :func:`repro.kernels.spmv.ops.choose_layout` rather than passed); and
+  operands were last built with (``block``, ``tile_cap``, ``tiles``: the
+  real tiles, ``fill``, ``chosen``: picked by
+  :func:`repro.kernels.spmv.ops.choose_layout` rather than passed,
+  ``pad_tiles``: tiles that pad dst-block runs to whole groups, and
+  ``tiles_per_step``: tiles a grid step runs); and
   under ``"distributed"`` the sharded layout
   :class:`repro.core.distributed.DistributedBundle` was last built with:
   ``p``, ``vp``, ``n_pad``, ``cap`` (edge slots per shard), ``segments``
@@ -69,7 +74,7 @@ def span(name: str, **args):
     return jax.profiler.TraceAnnotation(name, **args)
 
 
-# kernel name -> grid steps per call, as last traced
+# kernel name -> real tiles per call, as last handed a layout
 GRID_STEPS: dict[str, int] = {}
 # kernel name -> the tile layout its operands were last built with
 LAYOUTS: dict[str, dict] = {}

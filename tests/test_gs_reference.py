@@ -16,13 +16,14 @@ import pytest
 
 from repro.core.solver import solve_variant
 from repro.graphs import DecompositionPlan, make_dataset, rmat_graph
-from repro.graphs.csr import Graph
+from repro.graphs.csr import Graph, build_blocked_coo
 from repro.kernels.spmv import (
     PallasGraph,
     spmv_gs_pass,
     spmv_gs_pass_multi,
     spmv_gs_pass_ref,
 )
+from repro.kernels.spmv.kernel import GROUP, MAX_TILES
 
 from test_decomposition import chain_sink_heavy_graph
 
@@ -262,3 +263,184 @@ def test_pallas_nosync_sweeps_compose_reference(name):
         ref = spmv_gs_pass_ref(g, ref, block=128, d=D, base=(1 - D) / g.n,
                                bias=g.bias, dmass=dmass)
     assert_close(r.pr, ref, sweeps=sweeps)
+
+
+# ---------------------------------------------------------------------------
+# Whole groups: a Gauss–Seidel grid step runs GROUP tiles of one dst block
+# ---------------------------------------------------------------------------
+
+# tiles in each dst block's run at block 128 / cap 128; 0 = the block has no
+# in-edge and only its coverage tile
+RUNS = {
+    "runs1": (1, 1, 1, 1),
+    "runs7": (7, 7, 0, 7),
+    "runs8": (8, 0, 8, 8),
+    "runs9": (9, 9, 9, 0),
+    "runs17": (17, 0, 17, 17),
+    "coverage_only": (0, 3, 0, 0),
+    "last_mid_group": (8, 16, 8, 11),
+}
+
+
+@functools.cache
+def runs_graph(name: str) -> Graph:
+    """Four dst blocks of 128 vertices whose runs hold ``RUNS[name]``
+    tiles at cap 128: each block's tiles spread over the four src blocks
+    (below it, read fresh, and at or above it), each pair's tiles full but
+    the last."""
+    rng = np.random.default_rng(sum(RUNS[name]))
+    src, dst = [], []
+    for j, tiles in enumerate(RUNS[name]):
+        for s, t in enumerate(np.array_split(np.arange(tiles), 4)):
+            if not t.size:
+                continue
+            m = int(rng.integers((t.size - 1) * 128 + 1, t.size * 128 + 1))
+            src.append(s * 128 + rng.integers(0, 128, m))
+            dst.append(j * 128 + rng.integers(0, 128, m))
+    return Graph.from_edges(512, np.concatenate(src), np.concatenate(dst))
+
+
+@functools.cache
+def pallas_runs(name: str) -> PallasGraph:
+    return PallasGraph.build(runs_graph(name), block=128, tile_cap=128)
+
+
+def real_positions(pg: PallasGraph, ref) -> np.ndarray:
+    """Where ``ref``'s tiles sit in ``pg``'s padded layout: each dst
+    block's run starts with its real tiles."""
+    padded = np.asarray(pg.tile_dst_block)
+    return np.concatenate([np.flatnonzero(padded == j)[:n] for j, n in
+                           enumerate(np.bincount(ref.tile_dst_block))])
+
+
+@pytest.mark.parametrize("name", RUNS)
+def test_run_lengths_build_their_tiles(name):
+    g = runs_graph(name)
+    ref = build_blocked_coo(g, block=128, tile_cap=128)
+    runs = np.bincount(ref.tile_dst_block, minlength=4)
+    assert list(runs) == [max(1, k) for k in RUNS[name]]
+    pg = pallas_runs(name)
+    assert list(np.bincount(np.asarray(pg.tile_dst_block))) == \
+        [-(-k // GROUP) * GROUP for k in runs]
+
+
+def multi_pass(g: Graph, pg: PallasGraph, rows: int):
+    """One multi-vector pass of ``rows`` distinct teleport rows, and its
+    reference."""
+    rng = np.random.default_rng(rows)
+    tele = rng.random((rows, g.n))
+    tele /= tele.sum(axis=1, keepdims=True)
+    base = tele * ((1 - D) + D * rng.uniform(0, 0.2, (rows, 1)))
+    pr = tele.astype(np.float32)
+    wt = pg.tiles_valid if pg.tiles_weight is None else pg.tiles_weight
+    out = unblocked(spmv_gs_pass_multi(
+        blocked(pr, pg), pg.inv_out_blocks, blocked(np.ones(g.n), pg),
+        jnp.zeros((1, rows), jnp.float32), blocked(base, pg),
+        jnp.asarray([[D]], jnp.float32),
+        pg.tiles_src_local, pg.tiles_dst_local, pg.tiles_valid, wt,
+        pg.tile_src_block, pg.tile_dst_block, block=pg.block), pg)
+    ref = spmv_gs_pass_ref(g, pr, block=pg.block, d=D,
+                           base=base.astype(np.float32))
+    return out, ref
+
+
+@pytest.mark.parametrize("kernel", ("single", "multi"))
+@pytest.mark.parametrize("name", RUNS)
+def test_gs_pass_on_run_lengths_matches_reference(name, kernel):
+    """Runs shorter than a group, of exactly one, one past it, over two,
+    a block with only its coverage tile, and a last run that ends mid
+    group: both kernels sweep as the reference does."""
+    g, pg = runs_graph(name), pallas_runs(name)
+    if kernel == "single":
+        pr, base = uniform(g), (1 - D) / g.n
+        out = run_gs_pass(g, pg, pr, base=base)
+        assert_close(out, spmv_gs_pass_ref(g, pr, block=128, d=D, base=base))
+    else:
+        out, ref = multi_pass(g, pg, 8)
+        for row in range(8):
+            assert_close(out[row], ref[row])
+
+
+def with_pad_group(pg: PallasGraph) -> PallasGraph:
+    """``pg`` with one more all-pad group at the end of every dst block's
+    run: zero validity and weight, the run's dst block, its last tile's src
+    block."""
+    db = np.asarray(pg.tile_dst_block)
+    ends = np.flatnonzero(np.r_[db[1:] != db[:-1], True]) + 1
+    at = np.repeat(ends, GROUP)
+
+    def grow(x, fill):
+        x = np.asarray(x)
+        return jnp.asarray(np.insert(x, at, fill, axis=0))
+
+    return pg._replace(
+        tiles_src_local=grow(pg.tiles_src_local, 0),
+        tiles_dst_local=grow(pg.tiles_dst_local, 0),
+        tiles_valid=grow(pg.tiles_valid, 0.0),
+        tiles_weight=(None if pg.tiles_weight is None
+                      else grow(pg.tiles_weight, 0.0)),
+        tile_src_block=grow(pg.tile_src_block,
+                            np.asarray(pg.tile_src_block)[at - 1]),
+        tile_dst_block=grow(pg.tile_dst_block, db[at - 1]))
+
+
+@pytest.mark.parametrize("kernel", ("single", "multi"))
+@pytest.mark.parametrize("name", GRAPHS)
+def test_pad_groups_leave_ranks_bitwise(name, kernel):
+    """A pad tile adds exact zeros, in tile order: a layout with a whole
+    extra group of pads in every run gives the same ranks bit for bit."""
+    g = weighted(name)
+    pg = pallas(name, 128, 128, weights=True)
+    more = with_pad_group(pg)
+    assert more.tiles_src_local.shape[0] == \
+        pg.tiles_src_local.shape[0] + GROUP * pg.n_blocks
+    if kernel == "single":
+        pr, base = uniform(g), (1 - D) / g.n
+        a = run_gs_pass(g, pg, pr, base=base, bias=g.bias, dmass=1e-5)
+        b = run_gs_pass(g, more, pr, base=base, bias=g.bias, dmass=1e-5)
+    else:
+        a, b = multi_pass(g, pg, 8)[0], multi_pass(g, more, 8)[0]
+    assert np.array_equal(np.asarray(a, np.float32).view(np.uint32),
+                          np.asarray(b, np.float32).view(np.uint32))
+
+
+@pytest.mark.parametrize(
+    "name,layout",
+    [(n, lay) for n in GRAPHS for lay in LAYOUTS]
+    + [(n, (128, 128)) for n in RUNS],
+    ids=lambda x: "%dx%d" % x if isinstance(x, tuple) else x)
+def test_layout_pads_runs_to_whole_groups(name, layout):
+    """Every group of GROUP tiles lies in one dst block; pads carry zero
+    validity and weight and read their run's last src block; the real
+    tiles are build_blocked_coo's, in its order; the layout records its
+    real and pad tiles, and stays within one kernel call."""
+    if name in RUNS:
+        g, pg = runs_graph(name), pallas_runs(name)
+    else:
+        g, pg = weighted(name), pallas(name, *layout, weights=True)
+    ref = build_blocked_coo(g, block=pg.block, tile_cap=layout[1])
+    db = np.asarray(pg.tile_dst_block)
+    total = db.size
+    assert total % GROUP == 0 and total <= MAX_TILES
+    assert (db.reshape(-1, GROUP) == db[::GROUP, None]).all()
+    assert (np.diff(db) >= 0).all()
+    real = real_positions(pg, ref)
+    for field in ("tiles_src_local", "tiles_dst_local", "tiles_valid",
+                  "tiles_weight", "tile_src_block", "tile_dst_block"):
+        got, want = getattr(pg, field), getattr(ref, field)
+        assert (got is None) == (want is None), field
+        if want is not None:
+            np.testing.assert_array_equal(np.asarray(got)[real], want)
+    pad = np.ones(total, bool)
+    pad[real] = False
+    lanes = [np.asarray(pg.tiles_valid)[pad]]
+    if pg.tiles_weight is not None:
+        lanes.append(np.asarray(pg.tiles_weight)[pad])
+    assert all((x == 0).all() for x in lanes)
+    sb = np.asarray(pg.tile_src_block)
+    for i in np.flatnonzero(pad):  # a pad repeats its run's last src block
+        assert db[i - 1] == db[i] and sb[i] == sb[i - 1]
+    lay = pg.layout
+    assert (lay.tiles, lay.pad_tiles) == (ref.num_tiles, int(pad.sum()))
+    assert lay.pad_tiles <= (GROUP - 1) * pg.n_blocks
+    assert lay.tiles_per_step == GROUP

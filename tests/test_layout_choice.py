@@ -16,6 +16,7 @@ from repro.graphs.csr import (
 )
 from repro.kernels.spmv import ops
 from repro.kernels.spmv.kernel import (
+    GROUP,
     MAX_TILES,
     VMEM_CAPACITY,
     multi_pass_vmem_bytes,
@@ -89,6 +90,14 @@ def test_choice_honours_the_vmem_limit(kron, monkeypatch):
     assert multi_pass_vmem_bytes(-(-kron.n // b2), b2, c2, 8) < need
 
 
+@pytest.mark.parametrize("slots", [1, 8, 64])
+def test_choice_ignores_group_padding(kron, slots):
+    """The pick weighs the tiles build_blocked_coo makes, as it did before
+    the Gauss–Seidel kernels ran whole groups: the pads a build adds, and
+    the tile bodies a group keeps in VMEM, move no pick."""
+    assert choose_layout(kron, rows=slots) == (256, 128)
+
+
 def test_choice_is_deterministic(kron):
     rng = np.random.default_rng(0)
     order = rng.permutation(kron.m)
@@ -113,7 +122,11 @@ def test_engine_without_a_layout_chooses_and_records_it(kron):
     eng = PPREngine(kron, slots=8, backend="pallas")
     lay = eng.layout
     assert lay.chosen and (lay.block, lay.tile_cap) == choose_layout(kron)
-    assert lay.tiles == eng._backend.pg.tiles_src_local.shape[0]
+    assert lay.tiles == blocked_tile_stats(kron, lay.block,
+                                           lay.tile_cap)["n_tiles"]
+    assert lay.tiles + lay.pad_tiles == \
+        eng._backend.pg.tiles_src_local.shape[0]
+    assert lay.tiles_per_step == GROUP
     assert lay.fill == pytest.approx(kron.m / (lay.tiles * lay.tile_cap))
     assert eng.cache_block == lay.block
     assert LAYOUTS["spmv_gs_pass_multi"] == lay._asdict()
@@ -131,9 +144,13 @@ def test_explicit_layout_is_built_as_before(opts, expect):
     assert not eng.layout.chosen
     assert (eng.layout.block, eng.layout.tile_cap) == expect
     pg, ref = eng._backend.pg, build_blocked_coo(g, *expect)
+    # each dst block's run starts with its real tiles, then pads to a group
+    real = np.concatenate([
+        np.flatnonzero(np.asarray(pg.tile_dst_block) == j)[:n]
+        for j, n in enumerate(np.bincount(ref.tile_dst_block))])
     for name in ("tiles_src_local", "tiles_dst_local", "tiles_valid",
                  "tile_src_block", "tile_dst_block"):
-        np.testing.assert_array_equal(np.asarray(getattr(pg, name)),
+        np.testing.assert_array_equal(np.asarray(getattr(pg, name))[real],
                                       getattr(ref, name))
 
 
@@ -141,9 +158,11 @@ def test_global_gs_solve_records_its_layout():
     g = rmat_graph(7, avg_degree=5, seed=1)
     v, pg = build_variant("pallas_nosync", g, block=32, tile_cap=64)
     v.run(pg, threshold=1e-6)
+    real = build_blocked_coo(g, block=32, tile_cap=64).num_tiles
     assert LAYOUTS["spmv_gs_pass"] == dict(
-        block=32, tile_cap=64, tiles=pg.tiles_src_local.shape[0],
-        fill=g.m / (pg.tiles_src_local.shape[0] * 64), chosen=False)
+        block=32, tile_cap=64, tiles=real, fill=g.m / (real * 64),
+        chosen=False, pad_tiles=pg.tiles_src_local.shape[0] - real,
+        tiles_per_step=GROUP)
 
 
 def test_serve_prints_the_layout(capsys):

@@ -16,6 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.spmv.kernel import (
+    GROUP,
     MAX_TILES,
     spmv_blocked,
     spmv_gs_pass,
@@ -25,10 +26,12 @@ from repro.kernels.spmv.kernel import (
 # chip_smoke.py's global layout: full webStanford at block 1024 / cap 128;
 # full socEpinions1 at the registry's PPR defaults (block 256 / cap 1024,
 # 8 slots); and the layout choose_layout picks for the Graph500 scale-16
-# graph of bench/graph.py at seed 0 (block 640 / cap 128, 12,650 tiles)
-WEB = dict(n_blocks=276, block=1024, T=76_371, cap=128)
-SOC = dict(n_blocks=297, block=256, T=84_415, cap=1024, b=8)
-CHOSEN = dict(n_blocks=103, block=640, T=12_650, cap=128, b=8)
+# graph of bench/graph.py at seed 0 (block 640 / cap 128, 12,650 tiles).
+# Tile counts as PallasGraph.build pads them to whole groups (76,371 +
+# 1,021 pads; 84,415 + 953; 12,650 + 398)
+WEB = dict(n_blocks=276, block=1024, T=77_392, cap=128)
+SOC = dict(n_blocks=297, block=256, T=85_368, cap=1024, b=8)
+CHOSEN = dict(n_blocks=103, block=640, T=13_048, cap=128, b=8)
 
 
 @pytest.fixture(scope="module")
@@ -80,8 +83,9 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, fn, widths):
 
 @pytest.mark.parametrize("extra", [0, 1])
 def test_tile_limit_is_where_smem_fills(one_chip, extra):
-    """MAX_TILES tiles compile; one more overflows the SMEM tile maps."""
-    widths = dict(CHOSEN, T=MAX_TILES + extra)
+    """MAX_TILES tiles compile; one more group overflows the SMEM tile
+    maps (a Gauss–Seidel kernel takes whole groups of tiles)."""
+    widths = dict(CHOSEN, T=MAX_TILES + extra * GROUP)
     args = _args(one_chip, **widths)["spmv_gs_pass_multi"]
     lower = jax.jit(lambda *a: spmv_gs_pass_multi(
         *a, block=widths["block"], interpret=False)).lower(*args)
@@ -135,14 +139,16 @@ def _mosaic_ops(lowered, name):
     ("spmv_gs_pass_multi", spmv_gs_pass_multi, CHOSEN),
 ])
 def test_tile_contraction_latches_bf16_one_hots(one_chip, kernel, fn, widths):
-    """Each kernel's two tile contractions are bf16 × bf16 MXU matmuls with
-    f32 accumulation (the split f32 operand against the bf16 one-hot), not
-    f32 one-hots at HIGHEST precision, which the v5e latches six times."""
+    """Each tile's two contractions are bf16 × bf16 MXU matmuls with f32
+    accumulation (the split f32 operand against the bf16 one-hot), not f32
+    one-hots at HIGHEST precision, which the v5e latches six times.  The
+    Jacobi kernel runs one tile a grid step, the Gauss–Seidel kernels a
+    group of GROUP, unrolled."""
     args = _args(one_chip, **widths)[kernel]
     lowered = jax.jit(
         lambda *a: fn(*a, block=widths["block"], interpret=False)).lower(*args)
     matmuls = _mosaic_ops(lowered, "tpu.matmul")
-    assert len(matmuls) == 2
+    assert len(matmuls) == 2 * (1 if kernel == "spmv_blocked" else GROUP)
     for operands, attrs in matmuls:
         lhs, rhs, acc = operands
         assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>"), operands
